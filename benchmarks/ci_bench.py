@@ -48,6 +48,11 @@ import sys
 import tempfile
 import time
 
+# a CPU count gate: pin JAX to the CPU before anything imports it, so on
+# a chip host neither this process nor the dist-smoke children it starts
+# (they inherit the environment) ever claim the accelerator
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 # allow `python benchmarks/ci_bench.py` (sys.path[0] = benchmarks/)
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
@@ -55,13 +60,13 @@ if _REPO not in sys.path:
 
 
 def _bench_run_py() -> dict:
-    from benchmarks.run import BENCHES
+    from benchmarks.run import BENCHES, MissingArtifacts
     out = {}
     for name, fn in BENCHES:
         try:
             us, derived = fn(False)
-        except Exception as e:       # e.g. roofline needs dryrun artifacts
-            print(f"skip run.{name}: {type(e).__name__}: {e}")
+        except MissingArtifacts as e:   # roofline needs dry-run artifacts
+            print(f"skip run.{name}: {e}")
             continue
         out[f"run.{name}"] = dict(value=1e6 / max(us, 1e-9),
                                   unit="calls_per_sec", derived=derived)

@@ -13,6 +13,10 @@ import argparse
 import time
 
 
+class MissingArtifacts(FileNotFoundError):
+    """A bench reads artifacts another command writes, and none exist."""
+
+
 def _timed(fn, *args, **kw):
     t0 = time.perf_counter()
     out = fn(*args, **kw)
@@ -53,9 +57,11 @@ def bench_table4(full: bool):
 
 
 def bench_roofline(full: bool):
-    from benchmarks.roofline import load_records
+    from benchmarks.roofline import ART, load_records
     recs, us = _timed(load_records, "pod16x16")
     ok = [r for r in recs if r["status"] == "ok" and "roofline" in r]
+    if not ok:
+        raise MissingArtifacts(f"no dry-run roofline records under {ART}")
     best = max(ok, key=lambda r: r["roofline"]["roofline_fraction"])
     return us, (f"cells={len(ok)};best_frac="
                 f"{best['roofline']['roofline_fraction']:.3f}"

@@ -77,8 +77,8 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat
 from repro.core.csr import CSRGraph
+from repro.core.dist_msbfs import first_devices
 from repro.core.exchange import exchange_expand, exchange_reduce_or
 from repro.core.hybrid import ALPHA_DEFAULT, BETA_DEFAULT, MAX_TRACE
 from repro.core.msbfs import (MAX_LANES, MSBFSResult, msbfs_engine_enqueue,
@@ -249,13 +249,8 @@ def _check_partition_2d(dg: DistGraph2D, mesh: Mesh) -> None:
 
 def mesh2d(pr: int, pc: int) -> Mesh:
     """``pr x pc`` grid mesh over the first ``pr*pc`` local devices."""
-    devs = jax.devices()
-    if len(devs) < pr * pc:
-        raise ValueError(
-            f"grid {pr}x{pc} needs {pr * pc} devices but only {len(devs)} "
-            f"jax devices — set XLA_FLAGS=--xla_force_host_platform_"
-            f"device_count={pr * pc} before the first jax import")
-    return Mesh(np.asarray(devs[:pr * pc]).reshape(pr, pc), ("row", "col"))
+    devs = first_devices(pr * pc, f"grid {pr}x{pc}")
+    return Mesh(np.asarray(devs).reshape(pr, pc), ("row", "col"))
 
 
 def dist2d_msbfs_engine_init(dg: DistGraph2D, mesh: Mesh, capacity: int,
@@ -467,7 +462,7 @@ def _dist2d_engine_run(row_ptr_s, colloc_s, srcloc_s,
 
     spec_dev = P(("row", "col"))
     specs = _state_specs_2d()
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_dev, spec_dev, spec_dev, specs),
         out_specs=specs, check_vma=False,
@@ -549,7 +544,7 @@ def _derive_parents_2d(row_ptr_s, colgid_s, srcloc_s, depth_full, roots, *,
         return jax.lax.all_gather(parent_loc, "row", tiled=True)
 
     spec_dev = P(("row", "col"))
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_dev, spec_dev, spec_dev, P(), P()),
         out_specs=P(), check_vma=False,

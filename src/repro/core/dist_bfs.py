@@ -31,7 +31,7 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.core import bitmap, compat
+from repro.core import bitmap
 from repro.core.csr import CSRGraph
 
 MAX_LAYERS = 64
@@ -98,8 +98,7 @@ def partition_graph(g: CSRGraph, ndev: int) -> DistGraph:
 
 
 def _flat_axis_index(axes, sizes):
-    # sizes come from the (static) mesh shape — jax.lax.axis_size does not
-    # exist on jax 0.4.x
+    # sizes come from the (static) mesh shape
     idx = jnp.int32(0)
     for name in axes:
         idx = idx * sizes[name] + jax.lax.axis_index(name)
@@ -216,7 +215,7 @@ def _dist_bfs_impl(row_ptr_s, col_s, srcloc_s, deg_s, root, *, mesh: Mesh,
     spec_dev = P(axes)   # leading dim sharded over all mesh axes jointly
     # out_specs=P(): outputs are replicated (all_gather / psum products);
     # the static VMA check can't see through the while_loop, so disable it.
-    parent_full, depth_full, layers = compat.shard_map(
+    parent_full, depth_full, layers = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_dev, spec_dev, spec_dev, spec_dev, P()),
         out_specs=(P(), P(), P()), check_vma=False,

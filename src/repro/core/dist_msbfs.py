@@ -49,7 +49,6 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat
 from repro.core.csr import CSRGraph
 from repro.core.dist_bfs import DistGraph, _flat_axis_index, partition_graph
 from repro.core.exchange import allreduce_or
@@ -312,7 +311,7 @@ def _dist_engine_run(row_ptr_s, col_s, srcloc_s, deg_s,
 
     spec_dev = P(axes)
     specs = _state_specs(axes)
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_dev, spec_dev, spec_dev, spec_dev, specs),
         out_specs=specs, check_vma=False,
@@ -396,7 +395,7 @@ def _derive_parents_dist(row_ptr_s, col_s, srcloc_s, depth_full, roots, *,
         return jax.lax.all_gather(parent_loc, axes, tiled=True)
 
     spec_dev = P(axes)
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_dev, spec_dev, spec_dev, P(), P()),
         out_specs=P(), check_vma=False,
@@ -495,16 +494,26 @@ def dist_msbfs_engine_retire(dg: DistGraph, state: DistPipelineState,
     return _retire_dist(dg.deg, state, lane_mask)
 
 
+def first_devices(need: int, what: str) -> list:
+    """The first ``need`` jax devices, or a ValueError naming the platform
+    and device count found (a CPU run can fake devices; a chip host has
+    the chips it has)."""
+    devs = jax.devices()
+    if len(devs) >= need:
+        return devs[:need]
+    platform = devs[0].platform
+    hint = (f"set XLA_FLAGS=--xla_force_host_platform_device_count={need} "
+            f"before the first jax import" if platform == "cpu" else
+            f"run on a host with at least {need} {platform} devices")
+    raise ValueError(f"{what} needs {need} devices but found {len(devs)} "
+                     f"{platform} device(s) — {hint}")
+
+
 def host_mesh(ndev: int) -> Mesh:
     """1-D mesh over the first ``ndev`` local devices (shared by the
     graph500 harness and the serving loop)."""
-    devs = jax.devices()
-    if len(devs) < ndev:
-        raise ValueError(
-            f"ndev={ndev} but only {len(devs)} jax devices — set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={ndev} "
-            f"before the first jax import")
-    return Mesh(np.asarray(devs[:ndev]), ("data",))
+    devs = first_devices(ndev, f"ndev={ndev}")
+    return Mesh(np.asarray(devs), ("data",))
 
 
 def dist_msbfs(dg: DistGraph, roots, mesh: Mesh, mode: str = "hybrid",

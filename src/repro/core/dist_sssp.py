@@ -53,7 +53,6 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat
 from repro.core.csr import CSRGraph, WeightedCSRGraph
 from repro.core.dist2d import (DistGraph2D, _check_partition_2d, mesh2d,
                                partition_graph_2d)
@@ -484,7 +483,7 @@ def _dist_sssp_run(row_ptr_s, col_s, srcloc_s, w_s, state: DistSSSPState, *,
 
     spec_dev = P(axes)
     specs = _state_specs_1d()
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_dev, spec_dev, spec_dev, spec_dev, specs),
         out_specs=specs, check_vma=False,
@@ -775,7 +774,7 @@ def _dist2d_sssp_run(row_ptr_s, colloc_s, srcloc_s, w_s,
 
     spec_dev = P(("row", "col"))
     specs = _state_specs_2d()
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_dev, spec_dev, spec_dev, spec_dev, specs),
         out_specs=specs, check_vma=False,

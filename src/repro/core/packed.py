@@ -25,8 +25,11 @@ neutralised by the ``pos < deg`` probe guard, the ``pos_e < deg`` fallback
 guard, and the segmented scan's read-out points all sitting before the pad
 region.
 
-``segment_or`` is the segmented-OR associative scan named by ROADMAP as
-the piece to share with the distributed partition.
+``segment_scan_rows`` is the one segmented row reduction behind the
+top-down step, the bottom-up fallback and the numeric semirings
+(``repro.traversal.semiring``). Edge-lane values are built lane-major
+(``[W, m]``): on a TPU the minor axis is tiled 128 wide, so an ``[m, W]``
+array with a few lane words would take tens of times its size.
 """
 from __future__ import annotations
 
@@ -110,30 +113,66 @@ def depth_slice_words(depth: jnp.ndarray, max_depth,
     return pack_lanes((depth >= min_depth) & (depth <= max_depth))
 
 
-def segment_or(vals: jnp.ndarray, row_ptr: jnp.ndarray) -> jnp.ndarray:
-    """Per-CSR-row bitwise OR of uint32[m, W] edge-lane words -> uint32[n, W].
+def segment_scan_rows(vals: jnp.ndarray, row_ptr: jnp.ndarray,
+                      src_idx: jnp.ndarray, add, zero) -> jnp.ndarray:
+    """Per-CSR-row ``add``-reduction of LANE-MAJOR edge values
+    ``[L, m] -> [L, n]``; empty rows produce ``zero``. ``src_idx[e]`` is
+    the row that owns edge slot ``e``.
 
-    CSR rows are contiguous runs of edge slots, so the row-OR is a textbook
-    segmented scan: an inclusive ``lax.associative_scan`` over
-    (word, segment-start-flag) pairs, read out at each row's last slot.
-    Empty rows produce 0. Slots past ``row_ptr[-1]`` (distributed edge-slab
-    padding) only extend the last segment beyond every read-out point, so
-    their values never reach an output row.
+    CSR rows are contiguous runs of edge slots, so this is a segmented
+    inclusive scan read out at each row's last slot. It runs as a
+    Hillis-Steele doubling loop: step ``s`` folds slot ``i - s`` into slot
+    ``i`` while both sit in one row, so ``ceil(log2(longest row))`` steps
+    of whole-array ``add`` finish it. Every step has the same shape and the
+    edge axis stays the minor (densely tiled) one on a TPU. A slot's place
+    in its row comes from ``src_idx``, not from a cumulative max over the
+    row starts: on a TPU that scan over millions of slots takes most of a
+    minute to compile. ``add`` must be associative and commutative; for OR
+    and min the result is exact. Slots past ``row_ptr[-1]`` (distributed
+    edge-slab padding) only feed later slots, all past every read-out
+    point.
     """
-    m = vals.shape[0]
-    # row starts equal to m (trailing empty rows) must not flag slot m-1
-    flags = jnp.zeros((m,), jnp.bool_).at[row_ptr[:-1]].set(True, mode="drop")
-
-    def comb(a, b):
-        va, fa = a
-        vb, fb = b
-        return jnp.where(fb[..., None], vb, va | vb), fa | fb
-
-    scanned, _ = jax.lax.associative_scan(comb, (vals, flags))
+    m = vals.shape[-1]
+    n = row_ptr.shape[0] - 1
+    if m == 0:
+        return jnp.full(vals.shape[:-1] + (n,), zero, vals.dtype)
+    back = jnp.arange(m, dtype=jnp.int32) - row_ptr[src_idx]  # place in row
     deg = row_ptr[1:] - row_ptr[:-1]
+    longest = jnp.max(deg)
+
+    def double(c):
+        step, x = c
+        return 2 * step, jnp.where(back >= step,
+                                   add(x, jnp.roll(x, step, axis=-1)), x)
+
+    _, scanned = jax.lax.while_loop(lambda c: c[0] < longest, double,
+                                    (jnp.int32(1), vals))
     last = jnp.clip(row_ptr[1:] - 1, 0, m - 1)
-    return jnp.where((deg > 0)[:, None], scanned[last],
-                     jnp.zeros((), vals.dtype))
+    return jnp.where(deg > 0, scanned[..., last],
+                     jnp.asarray(zero, vals.dtype))
+
+
+def slot_rows(row_ptr: jnp.ndarray, m: int) -> jnp.ndarray:
+    """int32[m] row of each edge slot, from ``row_ptr`` alone (slots past
+    ``row_ptr[-1]`` land on the last row)."""
+    n = row_ptr.shape[0] - 1
+    return jnp.repeat(jnp.arange(n, dtype=jnp.int32),
+                      row_ptr[1:] - row_ptr[:-1], total_repeat_length=m)
+
+
+def gather_lanes(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """``table[idx].T`` built lane by lane: ``[n, L]`` rows gathered at
+    ``idx[m]`` as a lane-major ``[L, m]`` array. One 1-D gather per lane
+    column; a single batched gather would materialise ``[m, L]`` first."""
+    return jnp.stack([table[:, lane][idx] for lane in range(table.shape[1])])
+
+
+def segment_or(vals: jnp.ndarray, row_ptr: jnp.ndarray) -> jnp.ndarray:
+    """Per-CSR-row bitwise OR of uint32[m, W] edge-lane words -> uint32[n, W]
+    (``segment_scan_rows`` in the engines' row-major layout)."""
+    return segment_scan_rows(vals.T, row_ptr,
+                             slot_rows(row_ptr, vals.shape[0]),
+                             jnp.bitwise_or, 0).T
 
 
 def probe_xla(g: CSRGraph, frontier: jnp.ndarray, need: jnp.ndarray,
@@ -180,9 +219,10 @@ def bottomup_packed_step(g: CSRGraph, frontier: jnp.ndarray,
         # src row is already full, so they never contribute
         act = (residue[g.src_idx] & (pos_e >= max_pos)
                & (pos_e < g.deg[g.src_idx]))
-        contrib = jnp.where(act[:, None], frontier[g.col_idx],
-                            jnp.zeros((), frontier.dtype))
-        return found | (segment_or(contrib, g.row_ptr) & need)
+        contrib = jnp.where(act, gather_lanes(frontier, g.col_idx),
+                            jnp.zeros((), frontier.dtype))       # [W, m]
+        return found | (segment_scan_rows(contrib, g.row_ptr, g.src_idx,
+                                          jnp.bitwise_or, 0).T & need)
 
     return jax.lax.cond(jnp.any(residue), run_fallback, lambda f: f, found)
 
@@ -194,8 +234,10 @@ def topdown_packed_step(g: CSRGraph, frontier: jnp.ndarray,
     (masked to top-down lanes); per-row segmented OR gathers them. On the
     symmetrised Graph500 graphs this is exactly the TD expansion — the row
     owner collects from neighbours whose frontier bit is set."""
-    contrib = frontier[jnp.clip(g.col_idx, 0, frontier.shape[0] - 1)] & td_sel
-    return segment_or(contrib, g.row_ptr) & ~visited
+    col = jnp.clip(g.col_idx, 0, frontier.shape[0] - 1)
+    contrib = gather_lanes(frontier, col) & td_sel[:, None]       # [W, m]
+    return segment_scan_rows(contrib, g.row_ptr, g.src_idx,
+                             jnp.bitwise_or, 0).T & ~visited
 
 
 def lane_counters(g: CSRGraph, frontier_b: jnp.ndarray,

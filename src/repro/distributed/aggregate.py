@@ -24,15 +24,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core import compat
-
 
 def _ambient_axes():
-    try:
-        mesh = compat.get_abstract_mesh()
-    except Exception:
-        return None, 1
-    if mesh is None or not mesh.axis_names or mesh.size <= 1:
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.axis_names or mesh.size <= 1:
         return None, 1
     ndev = 1
     for s in dict(mesh.shape).values():
@@ -67,6 +62,6 @@ def owner_gather_scatter(node_feats: jnp.ndarray, senders: jnp.ndarray,
 
     spec = P(axes)   # leading dim sharded over all mesh axes jointly
     ed_specs = jax.tree.map(lambda _: spec, edge_data)
-    return compat.shard_map(body, in_specs=(spec, spec, spec, ed_specs),
-                            out_specs=spec, check_vma=False)(
+    return jax.shard_map(body, in_specs=(spec, spec, spec, ed_specs),
+                         out_specs=spec, check_vma=False)(
         node_feats, senders, receivers, edge_data)

@@ -30,7 +30,8 @@ import numpy as np
 
 from repro.core.csr import CSRGraph, to_numpy_adj
 from repro.core.hybrid import bfs
-from repro.core.msbfs import MAX_LANES, adaptive_lane_pool, msbfs_pipelined
+from repro.core.msbfs import (MAX_LANES, MSBFSResult, adaptive_lane_pool,
+                              msbfs_pipelined)
 from repro.graph.generator import rmat_graph, sample_roots
 from repro.graph.validate import validate_bfs_tree
 
@@ -51,6 +52,10 @@ class Graph500Result:
     teps: list[float] = field(default_factory=list)
     times: list[float] = field(default_factory=list)
     traversed: list[int] = field(default_factory=list)
+    roots: np.ndarray | None = None     # the sampled search keys
+    # batched runs: the timed sweep's full result (parents, depths), so a
+    # caller can validate any root of the very sweep that was timed
+    sweep: MSBFSResult | None = None
 
     @property
     def harmonic_mean_teps(self) -> float:
@@ -99,7 +104,8 @@ def run_graph500(scale: int, edgefactor: int, mode: str = "hybrid",
     if ndev > 1 or mesh is not None:
         raise ValueError("ndev > 1 requires batched=True (the sharded "
                          "engine is the MS-BFS one)")
-    res = Graph500Result(scale=scale, edgefactor=edgefactor, mode=mode)
+    res = Graph500Result(scale=scale, edgefactor=edgefactor, mode=mode,
+                         roots=roots)
 
     run = lambda r: bfs(g, r, mode, alpha, beta, max_pos, probe_impl,
                         skip_empty_fallback, td_impl)
@@ -162,7 +168,7 @@ def _run_batched(g: CSRGraph, roots: np.ndarray, scale: int, edgefactor: int,
                                       max_pos, probe_impl, lanes)
     res = Graph500Result(scale=scale, edgefactor=edgefactor,
                          mode=msbfs_mode, batched=True, lanes=lanes,
-                         ndev=ndev)
+                         ndev=ndev, roots=roots)
     rp_ci = to_numpy_adj(g) if validate else None
     if warmup:
         jax.block_until_ready(run())  # compile once per (shape, R, lanes)
@@ -170,6 +176,7 @@ def _run_batched(g: CSRGraph, roots: np.ndarray, scale: int, edgefactor: int,
     out = run()
     jax.block_until_ready(out.parent)
     dt = time.perf_counter() - t0
+    res.sweep = out
     edges = np.asarray(out.edges_traversed) // 2
     res.times.append(dt)
     res.traversed.extend(int(e) for e in edges)

@@ -11,6 +11,7 @@ import argparse
 import json
 
 from repro.graph.graph500 import run_graph500
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--probe-impl", default="xla", choices=["xla", "pallas"])
     ap.add_argument("--validate", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     res = run_graph500(args.scale, args.edgefactor, mode=args.mode,
                        num_roots=args.roots, seed=args.seed,

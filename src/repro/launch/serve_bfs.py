@@ -51,6 +51,7 @@ from repro.analytics.api import (AnalyticsRequest, BFSQuery, ClosenessQuery,
 from repro.analytics.api import QUERY_KINDS as _API_KINDS
 from repro.core.csr import WeightedCSRGraph
 from repro.graph.generator import rmat_weighted_graph, sample_roots
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving import AnalyticsService, ServiceConfig
 from repro.serving.trace import parse_mix, synthetic_trace
 
@@ -345,6 +346,7 @@ def main():
     ap.add_argument("--slo-reject-rate", type=float, default=None,
                     help="SLO: max reject rate over the rolling window")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.validate and (args.metrics_out or args.trace_out
                           or args.listen is not None or args.flight_out
                           or args.doctor_out):

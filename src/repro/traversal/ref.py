@@ -12,22 +12,25 @@ def dijkstra_reference(row_ptr: np.ndarray, col_idx: np.ndarray,
     delta-stepping engine is property-tested against. Returns float64[n]
     distances with inf unreached; handles parallel edges, zero weights and
     disconnected graphs (non-negative weights assumed, as enforced by
-    ``from_weighted_edges``)."""
-    n = len(row_ptr) - 1
-    dist = np.full(n, np.inf)
+    ``from_weighted_edges``). Sums are float64; the loop runs over Python
+    lists, which index faster than NumPy scalars."""
+    row_ptr = np.asarray(row_ptr).tolist()
+    col_idx = np.asarray(col_idx).tolist()
+    weights = np.asarray(weights, np.float64).tolist()
+    dist = [np.inf] * (len(row_ptr) - 1)
     dist[root] = 0.0
     heap = [(0.0, root)]
     while heap:
         d, u = heapq.heappop(heap)
         if d > dist[u]:
             continue                   # stale entry
-        for e in range(row_ptr[u], row_ptr[u + 1]):
-            v = col_idx[e]
-            nd = d + weights[e]
+        lo, hi = row_ptr[u], row_ptr[u + 1]
+        for v, w in zip(col_idx[lo:hi], weights[lo:hi]):
+            nd = d + w
             if nd < dist[v]:
                 dist[v] = nd
                 heapq.heappush(heap, (nd, v))
-    return dist
+    return np.asarray(dist, np.float64)
 
 
 def to_numpy_weighted(wg) -> tuple[np.ndarray, np.ndarray, np.ndarray]:

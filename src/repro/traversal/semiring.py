@@ -22,8 +22,9 @@ same step shape over *numeric* semirings:
 
 Two execution strategies mirror the packed TD/BU split:
 
-* ``segment_reduce`` — edge-parallel associative scan over CSR rows (the
-  generalized ``segment_or``): O(m * L), covers any degree; and
+* ``segment_reduce`` — edge-parallel segmented scan over CSR rows (the
+  generalized ``segment_or``): O(m * L * log(max degree)), covers any
+  degree; and
 * the MAX_POS-style *gather-relax* for the tropical semiring
   (``repro.kernels.semiring_relax``): each vertex gathers its first
   ``max_pos`` neighbours' lane values (+ edge weight, min-accumulate),
@@ -40,6 +41,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.csr import CSRGraph
+from repro.core.packed import (gather_lanes, segment_scan_rows,
+                               slot_rows)
 
 __all__ = ["BOOLEAN", "PLUS_TIMES", "SEMIRINGS", "Semiring", "TROPICAL",
            "segment_reduce", "semiring_spmv", "tropical_relax"]
@@ -78,24 +81,12 @@ SEMIRINGS = {sr.name: sr for sr in (BOOLEAN, TROPICAL, PLUS_TIMES)}
 def segment_reduce(vals: jnp.ndarray, row_ptr: jnp.ndarray,
                    sr: Semiring) -> jnp.ndarray:
     """Per-CSR-row semiring ADD of edge-lane values [m, L] -> [n, L] —
-    ``packed.segment_or`` generalized to any (ADD, zero): an inclusive
-    ``lax.associative_scan`` over (value, segment-start-flag) pairs read
-    out at each row's last slot. Empty rows produce ``sr.zero``; slots
-    past ``row_ptr[-1]`` only extend the last segment beyond every
-    read-out point."""
-    m = vals.shape[0]
-    flags = jnp.zeros((m,), jnp.bool_).at[row_ptr[:-1]].set(True, mode="drop")
-
-    def comb(a, b):
-        va, fa = a
-        vb, fb = b
-        return jnp.where(fb[..., None], vb, sr.add(va, vb)), fa | fb
-
-    scanned, _ = jax.lax.associative_scan(comb, (vals, flags))
-    deg = row_ptr[1:] - row_ptr[:-1]
-    last = jnp.clip(row_ptr[1:] - 1, 0, m - 1)
-    return jnp.where((deg > 0)[:, None], scanned[last],
-                     jnp.asarray(sr.zero, vals.dtype))
+    ``packed.segment_scan_rows`` in row-major layout. Empty rows produce
+    ``sr.zero``; slots past ``row_ptr[-1]`` only extend the last segment
+    beyond every read-out point."""
+    return segment_scan_rows(vals.T, row_ptr,
+                             slot_rows(row_ptr, vals.shape[0]), sr.add,
+                             sr.zero).T
 
 
 def semiring_spmv(g: CSRGraph, vals: jnp.ndarray, weights, sr: Semiring,
@@ -111,10 +102,12 @@ def semiring_spmv(g: CSRGraph, vals: jnp.ndarray, weights, sr: Semiring,
     (``packed.topdown_packed_step`` modulo the visited mask) — the
     cross-check pinning the generic path to the packed engines.
     """
-    contrib = vals[jnp.clip(g.col_idx, 0, vals.shape[0] - 1)]   # [m, L]
+    col = jnp.clip(g.col_idx, 0, vals.shape[0] - 1)
+    contrib = gather_lanes(vals, col)                             # [L, m]
     if weights is not None:
-        contrib = sr.mul(contrib, weights.astype(vals.dtype)[:, None])
-    return segment_reduce(contrib, g.row_ptr, sr)
+        contrib = sr.mul(contrib, weights.astype(vals.dtype))
+    return segment_scan_rows(contrib, g.row_ptr, g.src_idx, sr.add,
+                             sr.zero).T
 
 
 def _relax_fallback(g: CSRGraph, weights: jnp.ndarray, vals: jnp.ndarray,
@@ -125,10 +118,11 @@ def _relax_fallback(g: CSRGraph, weights: jnp.ndarray, vals: jnp.ndarray,
     past every read-out point, same argument as ``segment_or``."""
     pos_e = jnp.arange(g.m, dtype=jnp.int32) - g.row_ptr[g.src_idx]
     act = (pos_e >= max_pos) & (pos_e < g.deg[g.src_idx])
-    cand = vals[jnp.clip(g.col_idx, 0, vals.shape[0] - 1)] \
-        + weights.astype(vals.dtype)[:, None]
-    cand = jnp.where(act[:, None], cand, INF)
-    return segment_reduce(cand, g.row_ptr, TROPICAL)
+    cand = gather_lanes(vals, jnp.clip(g.col_idx, 0, vals.shape[0] - 1)) \
+        + weights.astype(vals.dtype)                              # [L, m]
+    cand = jnp.where(act, cand, INF)
+    return segment_scan_rows(cand, g.row_ptr, g.src_idx, TROPICAL.add,
+                             INF).T
 
 
 def tropical_relax(g: CSRGraph, weights: jnp.ndarray, vals: jnp.ndarray,
@@ -145,7 +139,7 @@ def tropical_relax(g: CSRGraph, weights: jnp.ndarray, vals: jnp.ndarray,
     deeper-row residue cond-skipped into the segmented scan — the same
     probe + fallback structure as the packed bottom-up step.
     """
-    if g.m == 0:   # edgeless: the associative scan has no slots to scan
+    if g.m == 0:   # edgeless: nothing relaxes
         return jnp.full((g.n, vals.shape[1]), jnp.inf, vals.dtype)
     if impl == "pallas":
         from repro.kernels import semiring_relax

@@ -245,7 +245,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 from repro.core import exchange
-from repro.core.compat import shard_map
+from jax import shard_map
 from repro.distributed.compression import sparse_budget
 
 devs = np.array(jax.devices()[:4]).reshape(2, 2)
@@ -401,6 +401,21 @@ def test_mesh_grid_mismatch_raises():
         dist2d_msbfs_engine_init(
             dg, Mesh(np.asarray(jax.devices()[:1]), ("data",)), capacity=1)
 
+
+def test_mesh_helpers_name_platform_and_device_count():
+    """Too few devices: the error names what JAX found, and the remedy
+    fits that platform (forced host devices only help on the CPU)."""
+    import jax
+    from repro.core.dist2d import mesh2d
+    from repro.core.dist_msbfs import host_mesh
+    devs = jax.devices()
+    found = f"found {len(devs)} {devs[0].platform} device"
+    with pytest.raises(ValueError, match=found) as e1:
+        host_mesh(len(devs) + 1)
+    with pytest.raises(ValueError, match=found) as e2:
+        mesh2d(len(devs) + 1, 1)
+    for err in (e1, e2):
+        assert "xla_force_host_platform_device_count" in str(err.value)
 
 ENGINE_GRID_CODE = """
 import numpy as np

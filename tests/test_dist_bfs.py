@@ -57,7 +57,7 @@ def test_dist_bfs_pallas_probe():
 
 OWNER_AGG_CODE = """
 import jax, jax.numpy as jnp, numpy as np
-from repro.core.compat import set_mesh
+from jax import set_mesh
 from repro.distributed.aggregate import owner_gather_scatter
 
 n, e, d = 64, 256, 8   # divisible by 8 devices

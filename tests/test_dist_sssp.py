@@ -237,9 +237,9 @@ def test_dist2d_sssp_compressed_bytes_track_frontier():
 
 EXCHANGE_VALUES_CODE = """
 import numpy as np
+import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.core import compat
 from repro.core.dist_msbfs import host_mesh
 from repro.core.exchange import (allreduce_min, exchange_reduce_min,
                                  gather_values)
@@ -249,9 +249,9 @@ mesh = host_mesh(2)
 INF = np.float32(np.inf)
 
 def run(vals, fn):
-    return compat.shard_map(fn, mesh=mesh, in_specs=(P("data"),),
-                            out_specs=(P("data"), P("data")),
-                            check_vma=False)(vals)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(P("data"),),
+                         out_specs=(P("data"), P("data")),
+                         check_vma=False)(vals)
 # fn returns per-device (block[1, ...], bytes[1]) so both carry the
 # device axis the out_specs name
 

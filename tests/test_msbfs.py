@@ -135,7 +135,7 @@ def lane_word_bits(bits):
     packed.LANE_WORD_BITS = bits
     try:
         if bits == 64:
-            with jax.experimental.enable_x64():
+            with jax.enable_x64(True):
                 yield
         else:
             yield
@@ -150,7 +150,7 @@ def test_word_dtype_x64_guard_names_fix():
     old = packed.LANE_WORD_BITS
     packed.LANE_WORD_BITS = 64
     try:
-        with jax.experimental.disable_x64():
+        with jax.enable_x64(False):
             with pytest.raises(RuntimeError) as exc:
                 packed.word_dtype()
     finally:
