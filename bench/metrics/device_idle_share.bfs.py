@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device,
+in % (1 - busy union / window), in the sweep cell. Moves ``teps``."""
+UNIT = "%"
+
+
+def read(run):
+    share = run.trace.idle_share()
+    return None if share is None else 100.0 * share
