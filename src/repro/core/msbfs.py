@@ -64,10 +64,11 @@ import numpy as np
 
 from repro.core.csr import CSRGraph
 from repro.core.hybrid import ALPHA_DEFAULT, BETA_DEFAULT, MAX_TRACE
-from repro.core.packed import (LANE_WORD_BITS, MODES, adaptive_lane_pool,
-                               depth_slice_words, dispatch_packed_step,
-                               lane_counters, num_lane_words, pack_lanes,
-                               queue_claims, segment_or, segment_scan_rows,
+from repro.core.packed import (LANE_WORD_BITS, MODES, _dispatch_packed_step,
+                               adaptive_lane_pool, depth_slice_words,
+                               dispatch_packed_step, lane_counters,
+                               num_lane_words, pack_lanes, queue_claims,
+                               segment_or, segment_scan_rows,
                                select_direction, unpack_lanes, word_dtype)
 
 __all__ = [
@@ -92,6 +93,9 @@ class MSBFSResult(NamedTuple):
     trace_vf: jnp.ndarray        # int32[MAX_TRACE, R]
     trace_ef: jnp.ndarray        # int32[MAX_TRACE, R]
     trace_eu: jnp.ndarray        # int32[MAX_TRACE, R]
+    # int32 scalar on the device: steps whose bottom-up fallback scan ran
+    # (the pipelined engine; None from engines that do not count them)
+    bu_fallback_passes: jnp.ndarray | None = None
 
     def reached_words(self, max_depth=None, min_depth=0) -> jnp.ndarray:
         """Packed lane words over the depth band [min_depth, max_depth] —
@@ -277,6 +281,7 @@ class PipelineState(NamedTuple):
     queued: jnp.ndarray          # int32 scalar  number of roots enqueued
     next_root: jnp.ndarray       # int32 scalar  next queue slot to claim
     sweep_layers: jnp.ndarray    # int32 scalar  total engine steps run
+    bu_fallback_passes: jnp.ndarray  # int32 scalar  BU fallback passes run
     out_depth: jnp.ndarray       # int32[n, capacity+1]
     out_edges: jnp.ndarray       # int32[capacity+1]
     out_layers: jnp.ndarray      # int32[capacity+1]  0 = unanswered
@@ -321,6 +326,7 @@ def msbfs_engine_init(g: CSRGraph, capacity: int,
         queued=jnp.int32(0),
         next_root=jnp.int32(0),
         sweep_layers=jnp.int32(0),
+        bu_fallback_passes=jnp.int32(0),
         out_depth=jnp.full((n, cap + 1), -1, jnp.int32),
         out_edges=jnp.zeros((cap + 1,), jnp.int32),
         out_layers=jnp.zeros((cap + 1,), jnp.int32),
@@ -390,73 +396,80 @@ def _pipeline_body(g: CSRGraph, s: PipelineState, mode: str, alpha: float,
                    beta: float, max_pos: int,
                    probe_impl: str) -> PipelineState:
     """One engine step: refill idle lanes, advance one layer, flush finished
-    lanes to their output slots."""
+    lanes to their output slots. Every operation but the bottom-up
+    dispatch conditional runs under one of ``packed.STEP_SCOPES``."""
     n = g.n
     lanes = s.lane_qidx.shape[0]
     cap = s.queue.shape[0]
-    s = _refill(g, s, mode != "bottomup")
+    with jax.named_scope("bfs_refill"):
+        s = _refill(g, s, mode != "bottomup")
 
-    active = s.lane_qidx < cap
-    frontier_b = unpack_lanes(s.frontier, lanes)
-    visited_b = unpack_lanes(s.visited, lanes)
-    e_f, v_f, e_u = lane_counters(g, frontier_b, visited_b)
-    topdown = select_direction(mode, s.topdown, e_f, v_f, e_u, n,
-                               alpha, beta, lanes)
+    with jax.named_scope("bfs_direction"):
+        active = s.lane_qidx < cap
+        frontier_b = unpack_lanes(s.frontier, lanes)
+        visited_b = unpack_lanes(s.visited, lanes)
+        e_f, v_f, e_u = lane_counters(g, frontier_b, visited_b)
+        topdown = select_direction(mode, s.topdown, e_f, v_f, e_u, n,
+                                   alpha, beta, lanes)
 
-    live = active & (v_f > 0)
-    td_sel = pack_lanes(topdown & live)                       # uint32[W]
-    bu_sel = pack_lanes(~topdown & live)
+        live = active & (v_f > 0)
+        td_sel = pack_lanes(topdown & live)                   # uint32[W]
+        bu_sel = pack_lanes(~topdown & live)
 
     # per-root trace rows are indexed by the lane's OWN layer counter and
     # its queue slot, so a root's trace replays its serial run regardless
     # of which lane served it or when it was claimed
-    tr_row = jnp.clip(s.lane_layer, 0, MAX_TRACE - 1)
-    tr_col = jnp.where(active, s.lane_qidx, cap)
-    # int32 up front: under x64 a weak-int64 scatter value into the
-    # int32 trace will become an error in future jax
-    dir_vals = jnp.where(live, jnp.where(topdown, 0, 1),
-                         -1).astype(jnp.int32)
-    trace_dir = s.trace_dir.at[tr_row, tr_col].set(dir_vals)
-    trace_vf = s.trace_vf.at[tr_row, tr_col].set(v_f)
-    trace_ef = s.trace_ef.at[tr_row, tr_col].set(e_f)
-    trace_eu = s.trace_eu.at[tr_row, tr_col].set(e_u)
+    with jax.named_scope("bfs_trace"):
+        tr_row = jnp.clip(s.lane_layer, 0, MAX_TRACE - 1)
+        tr_col = jnp.where(active, s.lane_qidx, cap)
+        # int32 up front: under x64 a weak-int64 scatter value into the
+        # int32 trace will become an error in future jax
+        dir_vals = jnp.where(live, jnp.where(topdown, 0, 1),
+                             -1).astype(jnp.int32)
+        trace_dir = s.trace_dir.at[tr_row, tr_col].set(dir_vals)
+        trace_vf = s.trace_vf.at[tr_row, tr_col].set(v_f)
+        trace_ef = s.trace_ef.at[tr_row, tr_col].set(e_f)
+        trace_eu = s.trace_eu.at[tr_row, tr_col].set(e_u)
 
-    new = dispatch_packed_step(g, s.frontier, s.visited, td_sel, bu_sel,
-                               mode, max_pos, probe_impl)
+    new, fell_back = _dispatch_packed_step(g, s.frontier, s.visited, td_sel,
+                                           bu_sel, mode, max_pos, probe_impl)
 
-    new_b = unpack_lanes(new, lanes)
-    visited2 = s.visited | new
-    visited2_b = visited_b | new_b
-    lane_layer2 = s.lane_layer + active.astype(jnp.int32)
-    depth2 = jnp.where(new_b, lane_layer2[None, :], s.depth)
+    with jax.named_scope("bfs_flush"):
+        new_b = unpack_lanes(new, lanes)
+        visited2 = s.visited | new
+        visited2_b = visited_b | new_b
+        lane_layer2 = s.lane_layer + active.astype(jnp.int32)
+        depth2 = jnp.where(new_b, lane_layer2[None, :], s.depth)
 
-    # finish = frontier drained OR per-lane layer cap (mirrors the serial
-    # while-loop bound, and guarantees the drain loop terminates)
-    finished = active & (~new_b.any(axis=0) | (lane_layer2 >= MAX_TRACE))
+        # finish = frontier drained OR per-lane layer cap (mirrors the
+        # serial while-loop bound, and guarantees the drain loop ends)
+        finished = active & (~new_b.any(axis=0) | (lane_layer2 >= MAX_TRACE))
 
-    deg = g.deg.astype(jnp.int32)[:, None]
-    edges_l = jnp.sum(jnp.where(visited2_b, deg, 0), axis=0,
-                      dtype=jnp.int32)
-    fcol = jnp.where(finished, s.lane_qidx, cap)
-    out_depth = s.out_depth.at[:, fcol].set(depth2)
-    out_edges = s.out_edges.at[fcol].set(edges_l)
-    out_layers = s.out_layers.at[fcol].set(lane_layer2)
+        deg = g.deg.astype(jnp.int32)[:, None]
+        edges_l = jnp.sum(jnp.where(visited2_b, deg, 0), axis=0,
+                          dtype=jnp.int32)
+        fcol = jnp.where(finished, s.lane_qidx, cap)
+        out_depth = s.out_depth.at[:, fcol].set(depth2)
+        out_edges = s.out_edges.at[fcol].set(edges_l)
+        out_layers = s.out_layers.at[fcol].set(lane_layer2)
 
-    # retire finished lanes: zero their packed bits so _refill can seat a
-    # fresh root into the slot on the very next step
-    clear = pack_lanes(finished)                              # uint32[W]
-    return s._replace(
-        frontier=new & ~clear,
-        visited=visited2 & ~clear,
-        depth=jnp.where(finished[None, :], -1, depth2),
-        lane_layer=jnp.where(finished, 0, lane_layer2),
-        lane_qidx=jnp.where(finished, cap, s.lane_qidx),
-        topdown=topdown,
-        sweep_layers=s.sweep_layers + 1,
-        out_depth=out_depth, out_edges=out_edges, out_layers=out_layers,
-        trace_dir=trace_dir, trace_vf=trace_vf, trace_ef=trace_ef,
-        trace_eu=trace_eu,
-    )
+        # retire finished lanes: zero their packed bits so _refill can seat
+        # a fresh root into the slot on the very next step
+        clear = pack_lanes(finished)                          # uint32[W]
+        return s._replace(
+            frontier=new & ~clear,
+            visited=visited2 & ~clear,
+            depth=jnp.where(finished[None, :], -1, depth2),
+            lane_layer=jnp.where(finished, 0, lane_layer2),
+            lane_qidx=jnp.where(finished, cap, s.lane_qidx),
+            topdown=topdown,
+            sweep_layers=s.sweep_layers + 1,
+            bu_fallback_passes=(s.bu_fallback_passes
+                                + fell_back.astype(jnp.int32)),
+            out_depth=out_depth, out_edges=out_edges, out_layers=out_layers,
+            trace_dir=trace_dir, trace_vf=trace_vf, trace_ef=trace_ef,
+            trace_eu=trace_eu,
+        )
 
 
 @partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
@@ -518,7 +531,8 @@ def msbfs_engine_result(g: CSRGraph, state: PipelineState,
         parent=parent, depth=depth, num_layers=state.out_layers[:r],
         edges_traversed=state.out_edges[:r],
         trace_dir=state.trace_dir[:, :r], trace_vf=state.trace_vf[:, :r],
-        trace_ef=state.trace_ef[:, :r], trace_eu=state.trace_eu[:, :r])
+        trace_ef=state.trace_ef[:, :r], trace_eu=state.trace_eu[:, :r],
+        bu_fallback_passes=state.bu_fallback_passes)
 
 
 # ---------------------------------------------------------------------------

@@ -25,6 +25,11 @@ neutralised by the ``pos < deg`` probe guard, the ``pos_e < deg`` fallback
 guard, and the segmented scan's read-out points all sitting before the pad
 region.
 
+Each piece of one engine step runs under a ``jax.named_scope`` from
+``STEP_SCOPES``, so the device trace can split a step (the op-name path
+of every operation names its scope); the scopes are op metadata only and
+leave the compiled program as it was.
+
 ``segment_scan_rows`` is the one segmented row reduction behind the
 top-down step, the bottom-up fallback and the numeric semirings
 (``repro.traversal.semiring``). Edge-lane values are built lane-major
@@ -51,6 +56,13 @@ if LANE_WORD_BITS not in (32, 64):
         f"LANE_WORD_BITS must be 32 or 64, got {LANE_WORD_BITS}")
 
 MODES = ("hybrid", "topdown", "bottomup")
+
+# the named scopes of one engine step, in step order: lane refill, the
+# direction counters and choice, the per-root trace rows, the top-down
+# step, the bottom-up probe, its fallback scan, and the flush of finished
+# lanes (with the merge of the two directions' new bits)
+STEP_SCOPES = ("bfs_refill", "bfs_direction", "bfs_trace", "bfs_topdown",
+               "bfs_bu_probe", "bfs_bu_fallback", "bfs_flush")
 
 
 def word_dtype():
@@ -199,19 +211,22 @@ def probe_xla(g: CSRGraph, frontier: jnp.ndarray, need: jnp.ndarray,
 
 def bottomup_packed_step(g: CSRGraph, frontier: jnp.ndarray,
                          visited: jnp.ndarray, bu_sel: jnp.ndarray,
-                         max_pos: int, probe_impl: str) -> jnp.ndarray:
+                         max_pos: int, probe_impl: str):
     """Packed bottom-up: probe + lax.cond-skipped segmented-scan fallback.
-    Returns new frontier bits for bottom-up lanes (already & ~visited)."""
-    need = (~visited) & bu_sel
-    if probe_impl == "pallas":
-        from repro.kernels import msbfs_probe
-        acc = msbfs_probe(g.row_ptr, g.col_idx, frontier, need,
-                          max_pos=max_pos)
-    else:
-        acc = probe_xla(g, frontier, need, max_pos)
-    found = acc & need
+    Returns new frontier bits for bottom-up lanes (already & ~visited) and
+    a bool scalar: whether the fallback scan ran (some row past
+    ``max_pos`` still needed a parent)."""
+    with jax.named_scope("bfs_bu_probe"):
+        need = (~visited) & bu_sel
+        if probe_impl == "pallas":
+            from repro.kernels import msbfs_probe
+            acc = msbfs_probe(g.row_ptr, g.col_idx, frontier, need,
+                              max_pos=max_pos)
+        else:
+            acc = probe_xla(g, frontier, need, max_pos)
+        found = acc & need
 
-    residue = ((need & ~found) != 0).any(axis=-1) & (g.deg > max_pos)
+        residue = ((need & ~found) != 0).any(axis=-1) & (g.deg > max_pos)
 
     def run_fallback(found):
         pos_e = jnp.arange(g.m, dtype=jnp.int32) - g.row_ptr[g.src_idx]
@@ -224,7 +239,9 @@ def bottomup_packed_step(g: CSRGraph, frontier: jnp.ndarray,
         return found | (segment_scan_rows(contrib, g.row_ptr, g.src_idx,
                                           jnp.bitwise_or, 0).T & need)
 
-    return jax.lax.cond(jnp.any(residue), run_fallback, lambda f: f, found)
+    with jax.named_scope("bfs_bu_fallback"):
+        ran = jnp.any(residue)
+        return jax.lax.cond(ran, run_fallback, lambda f: f, found), ran
 
 
 def topdown_packed_step(g: CSRGraph, frontier: jnp.ndarray,
@@ -234,10 +251,11 @@ def topdown_packed_step(g: CSRGraph, frontier: jnp.ndarray,
     (masked to top-down lanes); per-row segmented OR gathers them. On the
     symmetrised Graph500 graphs this is exactly the TD expansion — the row
     owner collects from neighbours whose frontier bit is set."""
-    col = jnp.clip(g.col_idx, 0, frontier.shape[0] - 1)
-    contrib = gather_lanes(frontier, col) & td_sel[:, None]       # [W, m]
-    return segment_scan_rows(contrib, g.row_ptr, g.src_idx,
-                             jnp.bitwise_or, 0).T & ~visited
+    with jax.named_scope("bfs_topdown"):
+        col = jnp.clip(g.col_idx, 0, frontier.shape[0] - 1)
+        contrib = gather_lanes(frontier, col) & td_sel[:, None]   # [W, m]
+        return segment_scan_rows(contrib, g.row_ptr, g.src_idx,
+                                 jnp.bitwise_or, 0).T & ~visited
 
 
 def lane_counters(g: CSRGraph, frontier_b: jnp.ndarray,
@@ -275,25 +293,45 @@ def dispatch_packed_step(g: CSRGraph, frontier: jnp.ndarray,
     — shared by the single-batch sweep, the pipelined engine, and the
     per-device body of the distributed engine (all three must advance
     frontiers bit-for-bit identically)."""
+    return _dispatch_packed_step(g, frontier, visited, td_sel, bu_sel, mode,
+                                 max_pos, probe_impl)[0]
+
+
+def _dispatch_packed_step(g: CSRGraph, frontier: jnp.ndarray,
+                          visited: jnp.ndarray, td_sel: jnp.ndarray,
+                          bu_sel: jnp.ndarray, mode: str, max_pos: int,
+                          probe_impl: str):
+    """``dispatch_packed_step`` and a bool scalar: whether the bottom-up
+    fallback scan ran this layer (the pipelined engine counts them)."""
     if mode == "topdown":
-        return topdown_packed_step(g, frontier, visited, td_sel)
+        return (topdown_packed_step(g, frontier, visited, td_sel),
+                jnp.zeros((), jnp.bool_))
     if mode == "bottomup":
-        return bottomup_packed_step(g, frontier, visited, bu_sel,
-                                    max_pos, probe_impl)
+        return bottomup_packed_step(g, frontier, visited, bu_sel, max_pos,
+                                    probe_impl)
     # middle layers usually have EVERY lane on one side — cond-skip the
     # other direction's O(m)/O(n*max_pos) work (the packed analog of the
     # serial controller's lax.cond)
-    zero = jnp.zeros_like(visited)
-    new_td = jax.lax.cond(
-        jnp.any(td_sel != 0),
-        lambda: topdown_packed_step(g, frontier, visited, td_sel),
-        lambda: zero)
-    new_bu = jax.lax.cond(
-        jnp.any(bu_sel != 0),
+    # the bottom-up conditional itself stays outside the scopes: its
+    # branch holds both the probe and the fallback, and a scope on it would
+    # put the one inside the other's path
+    with jax.named_scope("bfs_direction"):
+        zero = jnp.zeros_like(visited)
+        any_td = jnp.any(td_sel != 0)
+    with jax.named_scope("bfs_topdown"):
+        new_td = jax.lax.cond(
+            any_td,
+            lambda: topdown_packed_step(g, frontier, visited, td_sel),
+            lambda: zero)
+    with jax.named_scope("bfs_direction"):
+        any_bu = jnp.any(bu_sel != 0)
+    new_bu, ran = jax.lax.cond(
+        any_bu,
         lambda: bottomup_packed_step(g, frontier, visited, bu_sel,
                                      max_pos, probe_impl),
-        lambda: zero)
-    return new_td | new_bu
+        lambda: (zero, jnp.zeros((), jnp.bool_)))
+    with jax.named_scope("bfs_flush"):
+        return new_td | new_bu, ran
 
 
 def queue_claims(lane_qidx: jnp.ndarray, next_root: jnp.ndarray,

@@ -11,6 +11,8 @@ they all land, for every engine and for the serving front door:
   (``recorder=`` kwarg; off by default, zero-cost when disabled).
 * :mod:`repro.obs.traceviz` — Chrome trace-event JSON export (Perfetto-
   loadable) of sweeps and service request lifecycles, + JSONL sink.
+* :mod:`repro.obs.spans` — ``span(name)``: a ``repro:<name>`` host span on
+  the profiler's clock (the service tick's phases), timed into a registry.
 
 ``Telemetry`` is the bundle the stack threads through — pass one to
 ``LaneEngine(telemetry=...)`` / ``ServiceConfig(telemetry=...)`` and it
@@ -30,11 +32,12 @@ from dataclasses import dataclass, field
 from repro.obs.doctor import (DoctorReport, Finding, diagnose, diagnose_log,
                               records_from_jsonl, replay_switch,
                               split_sweeps)
-from repro.obs.metrics import (DEFAULT_BUCKETS, Counter, Gauge, Histogram,
-                               MetricsRegistry, default_registry,
+from repro.obs.metrics import (DEFAULT_BUCKETS, SECONDS_BUCKETS, Counter,
+                               Gauge, Histogram, MetricsRegistry,
                                metrics_text)
 from repro.obs.server import ObservabilityServer
 from repro.obs.slo import SLOConfig, SLOMonitor
+from repro.obs.spans import span
 from repro.obs.sweeplog import (LayerRecord, SweepRecorder, drive_recorded,
                                 record_step, snapshot_state)
 from repro.obs.traceviz import (FlightSink, service_trace_events,
@@ -44,11 +47,11 @@ from repro.obs.traceviz import (FlightSink, service_trace_events,
 __all__ = [
     "Counter", "DEFAULT_BUCKETS", "DoctorReport", "Finding", "FlightSink",
     "Gauge", "Histogram", "LayerRecord", "MetricsRegistry",
-    "ObservabilityServer", "SLOConfig", "SLOMonitor", "SweepRecorder",
-    "Telemetry", "default_registry", "diagnose", "diagnose_log",
+    "ObservabilityServer", "SECONDS_BUCKETS", "SLOConfig", "SLOMonitor",
+    "SweepRecorder", "Telemetry", "diagnose", "diagnose_log",
     "drive_recorded", "metrics_text", "record_step",
     "records_from_jsonl", "replay_switch", "service_trace_events",
-    "snapshot_state", "split_sweeps", "sweep_trace_events",
+    "snapshot_state", "span", "split_sweeps", "sweep_trace_events",
     "validate_trace_events", "write_chrome_trace",
 ]
 

@@ -27,12 +27,15 @@ import threading
 
 __all__ = [
     "Counter", "DEFAULT_BUCKETS", "Gauge", "Histogram", "MetricsRegistry",
-    "default_registry", "metrics_text",
+    "SECONDS_BUCKETS", "metrics_text",
 ]
 
 # layer-clock sojourns and per-layer wall-ms both land comfortably here
 DEFAULT_BUCKETS = (1.0, 2.0, 5.0, 10.0, 20.0, 50.0, 100.0, 200.0, 500.0,
                    1000.0)
+# host seconds: a phase of a service tick up to a request's wait
+SECONDS_BUCKETS = (0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5,
+                   1.0, 2.5, 5.0, 10.0, 30.0, 60.0)
 
 _MAX_SERIES_DEFAULT = 1000
 
@@ -272,15 +275,6 @@ class MetricsRegistry:
         return "\n".join(out) + ("\n" if out else "")
 
 
-_DEFAULT = MetricsRegistry()
-
-
-def default_registry() -> MetricsRegistry:
-    """The process-wide registry (for callers that don't thread their own
-    ``Telemetry`` bundle through)."""
-    return _DEFAULT
-
-
-def metrics_text(registry: MetricsRegistry | None = None) -> str:
-    """Text exposition of ``registry`` (the process default when None)."""
-    return (registry or _DEFAULT).expose()
+def metrics_text(registry: MetricsRegistry) -> str:
+    """Text exposition of ``registry``."""
+    return registry.expose()

@@ -36,6 +36,15 @@ width don't ride the shared pools at all: they execute inline through
 ``answer_request`` on the shared ``LaneEngine`` — the SAME handler table
 as ``run_query``, so every answer the service produces is parity-checked
 against the offline path by construction.
+
+**Tracing.** Each tick's phases run in ``repro.obs.span`` host spans
+(``service.tick`` around ``service.dispatch``, ``service.launch``,
+``service.wait``, ``service.readout``, ``service.collect`` and
+``service.account``), which land in a profiler trace beside the device's
+operations and in the ``service_tick_phase_seconds{phase}`` histogram.
+Every ``RequestRecord`` carries ``time.perf_counter`` stamps of its
+submission, dispatch, seating (every slot served by a lane or flushed)
+and answer; the two waits go into ``service_wait_seconds{stage}``.
 """
 from __future__ import annotations
 
@@ -44,6 +53,7 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 
+import jax
 import numpy as np
 
 from repro.analytics.api import (AnalyticsAnswer, AnalyticsRequest,
@@ -57,6 +67,8 @@ from repro.analytics.khop import (BFSResult, ReachResult,
 from repro.analytics.meta import QueryMeta
 from repro.analytics.weighted import SSSPDistancesResult, _resolve_delta
 from repro.core.hybrid import ALPHA_DEFAULT, BETA_DEFAULT
+from repro.obs.metrics import SECONDS_BUCKETS
+from repro.obs.spans import span
 from repro.serving.admission import (AdmissionController, DONE, QUEUED,
                                      REJECTED, RUNNING)
 from repro.serving.stats import summarize
@@ -121,6 +133,13 @@ class RequestRecord:
     submit_layer: int = 0
     dispatch_layer: int = -1
     answer_layer: int = -1
+    # host-clock stamps (time.perf_counter): submitted, dispatched into an
+    # engine queue, seated (every slot served by a lane or already
+    # flushed), answered
+    t_submit: float | None = None
+    t_dispatch: float | None = None
+    t_seated: float | None = None
+    t_done: float | None = None
     answered_early: bool = False  # streamed mid-sweep, before lane flush
     answer: AnalyticsAnswer | None = None
     # kind-specific plan fields
@@ -514,7 +533,8 @@ class AnalyticsService:
             if request.id in self._records:
                 raise ValueError(f"duplicate request id {request.id!r}")
             rec = RequestRecord(request=request,
-                                submit_layer=self._layer)
+                                submit_layer=self._layer,
+                                t_submit=time.perf_counter())
             self._plan(rec)
             ok, reason = self._admission.admit(request.tenant)
             if not ok:
@@ -579,31 +599,67 @@ class AnalyticsService:
         collect answers. Returns True while there is work in flight."""
         with self._cv:
             t0 = time.perf_counter()
-            self._layer += 1
-            self._dispatch()
-            if self._packed is not None:
-                self._packed.step()
-            if self._tropical is not None:
-                self._tropical.step()
-            self._collect_packed()
-            self._collect_tropical()
-            occ = 0
-            if self._packed is not None:
-                occ += self._packed.active_lanes()
-            if self._tropical is not None:
-                occ += self._tropical.active_lanes()
-            self._occupancy.append(occ)
-            self._registry.counter(
-                "service_layers_total", "scheduler ticks").inc()
-            self._registry.gauge(
-                "service_occupancy_lanes",
-                "active engine lanes after the tick").set(occ)
-            if self.slo is not None:
-                self.slo.observe_queue_depth(self._admission.pending)
-                self.slo.evaluate()
+            reg = self._registry
+            with span("service.tick", reg):
+                self._layer += 1
+                with span("service.dispatch", reg):
+                    self._dispatch()
+                with span("service.launch", reg):
+                    if self._packed is not None:
+                        self._packed.step()
+                    if self._tropical is not None:
+                        self._tropical.step()
+                occupied = (self._collect_packed(), self._collect_tropical())
+                with span("service.account", reg):
+                    occ = 0
+                    for pool, lanes in zip((self._packed, self._tropical),
+                                           occupied):
+                        if pool is not None:
+                            occ += (pool.active_lanes() if lanes is None
+                                    else lanes)
+                    self._occupancy.append(occ)
+                    reg.counter(
+                        "service_layers_total", "scheduler ticks").inc()
+                    reg.gauge(
+                        "service_occupancy_lanes",
+                        "active engine lanes after the tick").set(occ)
+                    if self.slo is not None:
+                        self.slo.observe_queue_depth(self._admission.pending)
+                        self.slo.evaluate()
             self._wall += time.perf_counter() - t0
             self._cv.notify_all()
             return self._busy_locked()
+
+    def _observe_wait(self, stage: str, seconds: float) -> None:
+        self._registry.histogram(
+            "service_wait_seconds", "host seconds a request waited: "
+            "dispatch = submit to an engine queue, lane = queue to a lane",
+            ("stage",), buckets=SECONDS_BUCKETS).labels(
+                stage=stage).observe(seconds)
+
+    def _mark_dispatched(self, rec: RequestRecord) -> None:
+        rec.status = RUNNING
+        rec.dispatch_layer = self._layer
+        rec.t_dispatch = time.perf_counter()
+        self._observe_wait("dispatch", rec.t_dispatch - rec.t_submit)
+        self._admission.on_dispatch(rec.request.tenant)
+
+    def _mark_seated(self, running: list[RequestRecord],
+                     lane_qidx: np.ndarray, flushed: np.ndarray) -> None:
+        """Stamp ``t_seated`` on each running request whose every queue
+        slot is now served by a lane (``lane_qidx``) or already flushed
+        (``flushed``, bool[capacity + 1]): a read-out already on the
+        host."""
+        waiting = [r for r in running if r.t_seated is None]
+        if not waiting:
+            return
+        now = time.perf_counter()
+        served = flushed.copy()
+        served[lane_qidx] = True       # idle lanes mark the trash column
+        for rec in waiting:
+            if served[rec.slots].all():
+                rec.t_seated = now
+                self._observe_wait("lane", now - rec.t_dispatch)
 
     def _dispatch(self) -> None:
         still: deque[RequestRecord] = deque()
@@ -622,10 +678,8 @@ class AnalyticsService:
                 pool.recycle()        # drained epoch: slots go back to work
             if pool.fits(rec.roots.size):
                 rec.slots = pool.enqueue(rec.roots)
-                rec.status = RUNNING
-                rec.dispatch_layer = self._layer
+                self._mark_dispatched(rec)
                 self._running[rec.engine].append(rec)
-                self._admission.on_dispatch(rec.request.tenant)
             else:
                 blocked.add(rec.engine)
                 still.append(rec)
@@ -633,10 +687,10 @@ class AnalyticsService:
 
     def _run_batch(self, rec: RequestRecord) -> None:
         """Inline path for whole-graph / foreign-delta workloads: the
-        SAME ``answer_request`` the offline dispatcher uses."""
-        rec.status = RUNNING
-        rec.dispatch_layer = self._layer
-        self._admission.on_dispatch(rec.request.tenant)
+        SAME ``answer_request`` the offline dispatcher uses. It needs no
+        lane, so it is seated as it is dispatched."""
+        self._mark_dispatched(rec)
+        rec.t_seated = rec.t_dispatch
         self._finish(rec, answer_request(self.engine, rec.request),
                      early=False)
 
@@ -644,6 +698,7 @@ class AnalyticsService:
                 early: bool) -> None:
         rec.answer = answer
         rec.answer_layer = self._layer
+        rec.t_done = time.perf_counter()
         rec.answered_early = early
         rec.status = DONE
         self._admission.on_done(rec.request.tenant)
@@ -659,25 +714,36 @@ class AnalyticsService:
 
     # -- answer collection --------------------------------------------------
 
-    def _collect_packed(self) -> None:
+    def _collect_packed(self) -> int | None:
+        """Answer what the pool's read-out allows and retire the lanes
+        answered early. Returns the pool's active lanes after the tick,
+        counted from the read-out (None when the tick took none)."""
         running = self._running["packed"]
         if not running:
-            return
+            return None
         pool = self._packed
-        ro = pool.readout()
-        retire: list[int] = []
-        for rec in running:
-            got = self._try_answer_packed(rec, ro)
-            if got is None:
-                continue
-            answer, early, live_lanes = got
-            self._finish(rec, answer, early)
-            retire.extend(live_lanes)
-        if retire:
-            mask = np.zeros(pool.lanes, bool)
-            mask[retire] = True
-            pool.retire(mask)
-        self._running["packed"] = [r for r in running if r.status != DONE]
+        reg = self._registry
+        with span("service.wait", reg):
+            jax.block_until_ready(pool.state)
+        with span("service.readout", reg):
+            ro = pool.readout()
+        with span("service.collect", reg):
+            self._mark_seated(running, ro.lane_qidx, ro.out_layers > 0)
+            retire: list[int] = []
+            for rec in running:
+                got = self._try_answer_packed(rec, ro)
+                if got is None:
+                    continue
+                answer, early, live_lanes = got
+                self._finish(rec, answer, early)
+                retire.extend(live_lanes)
+            if retire:
+                mask = np.zeros(pool.lanes, bool)
+                mask[retire] = True
+                pool.retire(mask)
+            self._running["packed"] = [r for r in running
+                                       if r.status != DONE]
+        return int(ro.active().sum()) - len(retire)
 
     def _try_answer_packed(self, rec: RequestRecord, ro):
         """(answer, answered_early, live_lanes_to_retire) when the
@@ -752,35 +818,42 @@ class AnalyticsService:
                                extra=dict(chunk=int(rec.roots.size))))
         return AnalyticsAnswer(rec.request.id, res, res.meta), False, []
 
-    def _collect_tropical(self) -> None:
+    def _collect_tropical(self) -> int | None:
+        """Answer the flushed tropical requests. Returns the pool's active
+        lanes after the tick (None when the tick took no read-out)."""
         running = self._running["tropical"]
         if not running:
-            return
+            return None
         pool = self._tropical
-        out_steps = np.asarray(pool.state.out_steps)
-        out_trunc = np.asarray(pool.state.out_truncated)
-        for rec in running:
-            sl = rec.slots
-            steps = out_steps[sl]
-            if not (steps > 0).all():
-                continue
-            trunc = out_trunc[sl]
-            delta = (pool.delta if isinstance(pool.delta, tuple)
-                     else float(pool.delta))
-            res = SSSPDistancesResult(
-                sources=rec.roots, dist=pool.out_dist_cols(sl),
-                delta=delta, steps=steps.astype(np.int32),
-                truncated_lanes=trunc,
-                meta=QueryMeta(kind="sssp", layers=int(steps.max()),
-                               truncated=bool(trunc.any()),
-                               lanes=rec.lanes_used,
-                               ndev=self.config.ndev,
-                               extra=dict(grid=None, compress=False,
-                                          delta=delta)))
-            self._finish(rec, AnalyticsAnswer(rec.request.id, res,
-                                              res.meta), early=False)
-        self._running["tropical"] = [r for r in running
-                                     if r.status != DONE]
+        with span("service.readout", self._registry):
+            out_steps = np.asarray(pool.state.out_steps)
+            out_trunc = np.asarray(pool.state.out_truncated)
+            lane_qidx = np.asarray(pool.state.lane_qidx)
+        with span("service.collect", self._registry):
+            self._mark_seated(running, lane_qidx, out_steps > 0)
+            for rec in running:
+                sl = rec.slots
+                steps = out_steps[sl]
+                if not (steps > 0).all():
+                    continue
+                trunc = out_trunc[sl]
+                delta = (pool.delta if isinstance(pool.delta, tuple)
+                         else float(pool.delta))
+                res = SSSPDistancesResult(
+                    sources=rec.roots, dist=pool.out_dist_cols(sl),
+                    delta=delta, steps=steps.astype(np.int32),
+                    truncated_lanes=trunc,
+                    meta=QueryMeta(kind="sssp", layers=int(steps.max()),
+                                   truncated=bool(trunc.any()),
+                                   lanes=rec.lanes_used,
+                                   ndev=self.config.ndev,
+                                   extra=dict(grid=None, compress=False,
+                                              delta=delta)))
+                self._finish(rec, AnalyticsAnswer(rec.request.id, res,
+                                                  res.meta), early=False)
+            self._running["tropical"] = [r for r in running
+                                         if r.status != DONE]
+        return int((lane_qidx < pool.state.capacity).sum())
 
     def packed_result(self, derive_parents: bool = False):
         """``MSBFSResult`` over the packed pool's CURRENT epoch — the

@@ -401,3 +401,79 @@ def test_engine_queue_overflow_and_init_guards(g_rmat):
         msbfs_engine_init(g_rmat, capacity=0)
     with pytest.raises(ValueError, match="lanes"):
         msbfs_engine_init(g_rmat, capacity=4, lanes=0)
+
+
+# ---------------------------------------------------------------------------
+# Named scopes and the fallback-pass counter of the pipelined engine.
+# ---------------------------------------------------------------------------
+
+
+def _engine_args(g, roots=64):
+    st = msbfs_engine_enqueue(msbfs_engine_init(g, capacity=roots, lanes=64),
+                              jnp.arange(roots, dtype=jnp.int32))
+    return g, st, "hybrid", 14.0, 24.0, 8, "xla"
+
+
+@pytest.mark.parametrize("program", ["_drain", "msbfs_engine_step"])
+def test_engine_programs_carry_every_step_scope(g_rmat, program):
+    """Each piece of an engine step sits under one of ``STEP_SCOPES``; only
+    the drain loop's condition and the bottom-up dispatch conditional (a
+    container whose branch holds both the probe and the fallback) and its
+    predicate stay outside."""
+    lowered = getattr(ms, program).lower(*_engine_args(g_rmat))
+    text = lowered.as_text(debug_info=True)
+    for scope in packed.STEP_SCOPES:
+        assert scope in text, scope
+    import re
+    body = {"_drain": "jit(_drain)/while/body",
+            "msbfs_engine_step": "jit(msbfs_engine_step)"}[program]
+    names = re.findall(r'op_name="([^"]*)"', lowered.compile().as_text())
+    outside = {n for n in names if n.startswith(body + "/")
+               and not set(n.split("/")) & set(packed.STEP_SCOPES)}
+    assert outside <= {body + "/cond", body + "/convert_element_type"}
+
+
+def _fallback_steps(g, depth, trace_dir, max_pos=8):
+    """Engine steps whose bottom-up residue was non-empty, from the answers
+    of a sweep that seated every key at step 0 (so step ``t`` is layer
+    ``t`` of every lane): a bottom-up lane at layer ``t`` leaves a residue
+    when a row of degree over ``max_pos`` it has not reached has none of
+    its first ``max_pos`` neighbours at depth ``t``."""
+    rp, ci = to_numpy_adj(g)
+    deg = np.diff(rp)
+    pos = np.arange(max_pos)
+    valid = pos[None, :] < deg[:, None]
+    nbr = ci[np.minimum(rp[:-1, None] + pos[None, :], ci.size - 1)]
+    depth, trace_dir = np.asarray(depth), np.asarray(trace_dir)
+    steps = 0
+    for t in range(trace_dir.shape[0]):
+        for r in np.flatnonzero(trace_dir[t] == 1):
+            d = depth[:, r]
+            need = (d < 0) | (d > t)
+            found = ((d[nbr] == t) & valid).any(axis=1)
+            if (need & ~found & (deg > max_pos)).any():
+                steps += 1
+                break
+    return steps
+
+
+@pytest.mark.parametrize("graph,mode", [("rmat", "hybrid"),
+                                        ("rmat", "bottomup"),
+                                        ("ring", "bottomup")])
+def test_bu_fallback_passes_counts_residue_steps(g_rmat, graph, mode):
+    """The drain's counter equals the step loop's and the steps whose
+    bottom-up residue was non-empty; a graph with no row past ``max_pos``
+    never runs the fallback."""
+    g = g_rmat if graph == "rmat" else ring_graph(64)
+    roots = (sample_roots(g, 40, seed=21) if graph == "rmat"
+             else np.arange(0, 64, 8))
+    out = msbfs_pipelined(g, jnp.asarray(roots), mode, lanes=64)
+    state = msbfs_engine_enqueue(msbfs_engine_init(g, len(roots), lanes=64),
+                                 jnp.asarray(roots))
+    while not msbfs_engine_idle(state):
+        state = msbfs_engine_step(g, state, mode)
+    want = _fallback_steps(g, out.depth, out.trace_dir)
+    assert int(out.bu_fallback_passes) == int(state.bu_fallback_passes)
+    assert int(out.bu_fallback_passes) == want
+    assert (np.asarray(out.trace_dir) == 1).any()
+    assert (want > 0) == (graph == "rmat")

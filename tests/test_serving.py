@@ -416,3 +416,57 @@ np.testing.assert_array_equal(rk.answer.result.words,
 assert fk.sojourn - rk.sojourn >= 1
 print("ok")
 """, devices=ndev)
+
+
+# ---------------------------------------------------------------------------
+# Host-clock stamps, tick spans and the occupancy read from the read-out.
+# ---------------------------------------------------------------------------
+
+
+def _busy_service(wg):
+    """More one-key requests than lanes and than one epoch's slots, of the
+    pooled kinds and the inline batch path."""
+    svc = AnalyticsService(wg, lanes=8, slots=24, sssp_slots=8)
+    recs = []
+    for i in range(40):
+        kind = i % 4
+        q = (BFSQuery(sources=(i,)) if kind == 0 else
+             KHopQuery(sources=(i,), k=1) if kind == 1 else
+             SSSPQuery(sources=(i,)) if kind == 2 else
+             ReachQuery(sources=(i,), targets=(i + 1,)))
+        recs.append(svc.submit(q))
+    recs.append(svc.submit(ComponentsQuery(batch=32)))
+    return svc, recs
+
+
+def test_request_stamps_are_ordered(wg):
+    svc, recs = _busy_service(wg)
+    occupancy = []
+    while svc.busy():
+        svc.step()
+        occupancy.append(svc._occupancy[-1])
+        # the occupancy taken from the tick's read-out is the pools' own
+        assert occupancy[-1] == sum(p.active_lanes() for p in
+                                    (svc._packed, svc._tropical) if p)
+    assert max(occupancy) > 0
+    for r in recs:
+        assert r.status == DONE
+        assert r.t_submit <= r.t_dispatch <= r.t_seated <= r.t_done, r.kind
+    # of the requests the first tick dispatched, some waited for a lane
+    first = [r for r in recs if r.engine == "packed"
+             and r.dispatch_layer == 1]
+    assert len(first) > svc._packed.lanes
+    assert len({r.t_seated for r in first}) > 1
+
+
+def test_tick_phases_and_waits_in_metrics_text(wg):
+    svc, _ = _busy_service(wg)
+    svc.run_until_idle()
+    text = svc.metrics_text()
+    for phase in ("tick", "dispatch", "launch", "wait", "readout",
+                  "collect", "account"):
+        assert (f'service_tick_phase_seconds_count{{phase="service.'
+                f'{phase}"}}') in text, phase
+    for stage in ("dispatch", "lane"):
+        assert f'service_wait_seconds_count{{stage="{stage}"}}' in text
+    assert "# TYPE service_wait_seconds histogram" in text
