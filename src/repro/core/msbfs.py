@@ -46,7 +46,10 @@ Parent selection: parents are derived once at the end from the depth
 arrays (min-id neighbour one level up), so they are *valid* Graph500
 parents; serial ``bfs`` picks the min frontier-neighbour per layer, which
 coincides for the min-parent rule — tests assert exact parent equality on
-top of validator-level equivalence.
+top of validator-level equivalence. Up to 2**24 vertices the rule is one
+per-row minimum of the key ``(depth + 1) << 24 | id`` over reached row
+entries, exact because every step pulls a row from its entries: no
+reached entry of a reached vertex lies more than one level above it.
 
 The packed step formulations themselves (lane packing, the segmented-OR
 scan, the word-packed probe, per-lane direction dispatch) live in
@@ -125,6 +128,44 @@ class _State(NamedTuple):
 # takes one byte, since no lane runs past MAX_TRACE layers
 _PARENT_LANES_PER_WORD = 4
 assert MAX_TRACE < 255
+# the keyed rule's (depth + 1, id) key: the byte on top, the id below it
+_KEY_ID_BITS = 24
+_NO_KEY = 0xFFFFFFFF
+
+
+def _chunk_parents_keyed(g: CSRGraph, w: jnp.ndarray,
+                         shifts: jnp.ndarray) -> jnp.ndarray:
+    """Parents of the lanes packed in ``w`` (uint32[n], one ``depth + 1``
+    byte per lane at ``shifts``) from one gather of m words, ``w[col]``.
+
+    Per lane and row, the least key ``byte(u) << 24 | u`` over the reached
+    entries u gives their least depth and, at that depth, the least id.
+    Needs ``g.n <= 2**24``.
+    """
+    col = g.col_idx.astype(jnp.uint32)
+    at_col = (w[g.col_idx][None, :] >> shifts[:, None]) & 0xFF  # [k, m]
+    key = jnp.where(at_col != 0, (at_col << _KEY_ID_BITS) | col[None, :],
+                    jnp.uint32(_NO_KEY))
+    best = segment_scan_rows(key, g.row_ptr, g.src_idx, jnp.minimum,
+                             _NO_KEY)                              # [k, n]
+    own = (w[None, :] >> shifts[:, None]) & 0xFF   # the row's byte, no gather
+    # the empty key's top byte (255) is above any byte + 1, so never matches
+    ok = (best >> _KEY_ID_BITS) + 1 == own
+    return jnp.where(ok, (best & ((1 << _KEY_ID_BITS) - 1)).astype(jnp.int32),
+                     -1)
+
+
+def _chunk_parents_pair(g: CSRGraph, w: jnp.ndarray,
+                        shifts: jnp.ndarray) -> jnp.ndarray:
+    """``_chunk_parents_keyed``'s result from two gathers of m words,
+    ``w[col]`` and ``w[src]``, for ids of any width."""
+    n, src, col = g.n, g.src_idx, g.col_idx
+    at_col = (w[col][None, :] >> shifts[:, None]) & 0xFF    # [k, m]
+    at_src = (w[src][None, :] >> shifts[:, None]) & 0xFF
+    ok = (at_col != 0) & (at_col + 1 == at_src)
+    cand = jnp.where(ok, col[None, :], n).astype(jnp.int32)
+    best = segment_scan_rows(cand, g.row_ptr, src, jnp.minimum, n)
+    return jnp.where(best < n, best, -1)                    # [k, n]
 
 
 @jax.jit
@@ -133,34 +174,30 @@ def _derive_parents(g: CSRGraph, depth: jnp.ndarray,
     """parent[v, r] = min-id neighbour of v one level up in lane r.
 
     Four lanes at a time (``lax.map``): their ``depth + 1`` bytes share one
-    uint32 word per vertex, so two gathers of m words (at ``col`` and at
-    ``src``) serve four lanes, and the per-row minimum is one segmented
-    scan of ``[4, m]`` candidates. A gather on a TPU costs per index, not
-    per byte, so this is about four times fewer gathered indices than one
-    lane at a time. The min-id rule matches the serial steps'
+    uint32 word per vertex. A gather on a TPU costs per index, not per
+    byte, so four lanes cost what one does. With ``g.n <= 2**24`` a chunk
+    gathers m words once (``_chunk_parents_keyed``): the per-row minimum
+    of ``(depth + 1) << 24 | id`` over reached entries is exact. Every
+    step pulls a row from its entries, so no reached entry of a reached v
+    lies more than one level above v; the least depth among them is one
+    above v's exactly when v has a parent, and its least id is that
+    parent. Larger graphs gather at ``col`` and at ``src``
+    (``_chunk_parents_pair``). The min-id rule matches the serial steps'
     deterministic scatter-min parent choice.
     """
     n = g.n
     num_roots = roots.shape[0]
     if num_roots == 0:
         return jnp.zeros((n, 0), jnp.int32)
-    src, col = g.src_idx, g.col_idx
     k = _PARENT_LANES_PER_WORD
     chunks = -(-num_roots // k)
     biased = jnp.pad(depth + 1, ((0, 0), (0, chunks * k - num_roots)))
     shifts = 8 * jnp.arange(k, dtype=jnp.uint32)
     words = (biased.astype(jnp.uint32).reshape(n, chunks, k)
              << shifts).sum(axis=-1, dtype=jnp.uint32)         # [n, chunks]
-
-    def chunk_parents(w):                                   # uint32[n]
-        at_col = (w[col][None, :] >> shifts[:, None]) & 0xFF  # [k, m]
-        at_src = (w[src][None, :] >> shifts[:, None]) & 0xFF
-        ok = (at_col != 0) & (at_col + 1 == at_src)
-        cand = jnp.where(ok, col[None, :], n).astype(jnp.int32)
-        best = segment_scan_rows(cand, g.row_ptr, src, jnp.minimum, n)
-        return jnp.where(best < n, best, -1)                # [k, n]
-
-    parent = jax.lax.map(chunk_parents, words.T)            # [chunks, k, n]
+    rule = (_chunk_parents_keyed if n <= 1 << _KEY_ID_BITS
+            else _chunk_parents_pair)
+    parent = jax.lax.map(lambda w: rule(g, w, shifts), words.T)  # [chunks, k, n]
     parent = parent.reshape(chunks * k, n)[:num_roots].T
     lane = jnp.arange(num_roots)
     return parent.at[roots, lane].set(roots.astype(jnp.int32))

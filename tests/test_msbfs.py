@@ -477,3 +477,138 @@ def test_bu_fallback_passes_counts_residue_steps(g_rmat, graph, mode):
     assert int(out.bu_fallback_passes) == want
     assert (np.asarray(out.trace_dir) == 1).any()
     assert (want > 0) == (graph == "rmat")
+
+
+# ---------------------------------------------------------------------------
+# Parent derivation: one keyed per-row minimum per four lanes.
+# ---------------------------------------------------------------------------
+
+
+def _parents_numpy(g, depth, roots):
+    """The min-id row entry one level up, -1 where there is none, each
+    root its own parent."""
+    rp, ci = to_numpy_adj(g)
+    src = np.repeat(np.arange(g.n), np.diff(rp))
+    depth = np.asarray(depth)
+    want = np.full(depth.shape, -1, np.int64)
+    for lane, root in enumerate(np.asarray(roots)):
+        d = depth[:, lane]
+        up = (d[src] > 0) & (d[ci] == d[src] - 1)
+        best = np.full(g.n, g.n, np.int64)
+        np.minimum.at(best, src[up], ci[up])
+        want[:, lane] = np.where(best < g.n, best, -1)
+        want[root, lane] = root
+    return want
+
+
+def _retired_after(g, roots, steps):
+    """Depths of partial columns: every lane retired after ``steps`` steps."""
+    state = msbfs_engine_enqueue(
+        msbfs_engine_init(g, capacity=len(roots), lanes=len(roots)),
+        jnp.asarray(roots, jnp.int32))
+    for _ in range(steps):
+        state = msbfs_engine_step(g, state)
+    state = ms.msbfs_engine_retire(g, state, np.ones(len(roots), bool))
+    return msbfs_engine_result(g, state, derive_parents=False).depth
+
+
+def _parent_case(name):
+    """(graph, depth, roots) of one parent-derivation case."""
+    rng = np.random.default_rng(7)
+    if name.startswith("rmat"):
+        g = rmat_graph(10, 16, seed=0)
+        roots = sample_roots(g, int(name.split("_")[1]), seed=31)
+    elif name == "path_capped":
+        n = ms.MAX_TRACE + 10
+        v = np.arange(n - 1)
+        g = from_edges(v, v + 1, n)
+        roots = np.array([0, n // 2, n - 1, 5, 70])
+    elif name == "retired":
+        g = rmat_graph(9, 8, seed=3)
+        roots = sample_roots(g, 9, seed=32)
+        return g, _retired_after(g, roots, 2), roots
+    elif name == "self_loops_isolated":
+        n = 300          # vertices 250 and up have no edge
+        s, d = rng.integers(0, 250, 900), rng.integers(0, 250, 900)
+        loops = rng.integers(0, 250, 60)
+        g = from_edges(np.concatenate([s, loops]), np.concatenate([d, loops]),
+                       n, drop_self_loops=False)
+        roots = np.array([int(loops[0]), 260, 0, int(loops[1]), 299, 17])
+    elif name == "non_symmetric":
+        n = 400
+        s, d = rng.integers(0, n, 2400), rng.integers(0, n, 2400)
+        g = from_edges(s, d, n, symmetrize=False)
+        roots = np.array([0, 1, 2, 3, 4, 5, 6])
+    out = msbfs_pipelined(g, jnp.asarray(roots), "hybrid", lanes=64,
+                          derive_parents=False)
+    return g, out.depth, roots
+
+
+PARENT_CASES = ["rmat_1", "rmat_3", "rmat_4", "rmat_5", "rmat_64",
+                "path_capped", "retired", "self_loops_isolated",
+                "non_symmetric"]
+
+
+@pytest.mark.parametrize("case", PARENT_CASES)
+def test_derive_parents_matches_numpy_rule(case):
+    """``_derive_parents`` on engine depths equals the plain rule: full and
+    partial four-lane words, a lane capped at ``MAX_TRACE``, columns
+    flushed by ``msbfs_engine_retire``, self-loops, isolated vertices and
+    a CSR that is not symmetric."""
+    g, depth, roots = _parent_case(case)
+    if case == "path_capped":      # lane 0 stops before the path's end
+        assert int(np.asarray(depth)[:, 0].max()) == ms.MAX_TRACE
+        assert (np.asarray(depth)[:, 0] < 0).any()
+    if case == "retired":
+        assert int(np.asarray(depth).max()) == 2
+    got = ms._derive_parents(g, depth, jnp.asarray(roots, jnp.int32))
+    np.testing.assert_array_equal(np.asarray(got),
+                                  _parents_numpy(g, depth, roots))
+
+
+def _chunk_words(depth):
+    """The four-lane ``depth + 1`` words ``_derive_parents`` maps over."""
+    biased = np.asarray(depth, np.int64) + 1
+    biased = np.pad(biased, ((0, 0), (0, -biased.shape[1] % 4)))
+    chunks = biased.reshape(biased.shape[0], -1, 4) << (8 * np.arange(4))
+    return jnp.asarray(chunks.sum(axis=-1).T.astype(np.uint32))
+
+
+@pytest.mark.parametrize("case", ["rmat_64", "path_capped", "retired",
+                                  "self_loops_isolated", "non_symmetric"])
+def test_keyed_rule_equals_two_gather_rule(case):
+    """The per-chunk rule for ``n <= 2**24`` and the one kept for larger
+    graphs give the same parents."""
+    g, depth, _ = _parent_case(case)
+    shifts = 8 * jnp.arange(4, dtype=jnp.uint32)
+    for w in _chunk_words(depth):
+        np.testing.assert_array_equal(
+            np.asarray(ms._chunk_parents_keyed(g, w, shifts)),
+            np.asarray(ms._chunk_parents_pair(g, w, shifts)))
+
+
+@pytest.mark.parametrize("rule,gathers", [("keyed", 1), ("pair", 2)])
+def test_parent_map_gathers_words_once_per_chunk(rule, gathers):
+    """In the lowered program the map's body gathers the chunk's uint32[n]
+    words at m indices once (the two-gather rule: twice). The only other
+    gather over m indices is ``segment_scan_rows``' ``row_ptr[src_idx]``,
+    which does not depend on the chunk."""
+    import re
+    g = rmat_graph(8, 8, seed=0)
+    n, m = g.n, g.m
+    assert n + 1 != m and n <= 1 << 24
+    roots = jnp.asarray(sample_roots(g, 8, seed=1))
+    depth = msbfs_pipelined(g, roots, lanes=64, derive_parents=False).depth
+    if rule == "keyed":
+        lowered = ms._derive_parents.lower(g, depth, roots)
+    else:
+        shifts = 8 * jnp.arange(4, dtype=jnp.uint32)
+        lowered = jax.jit(lambda g, words: jax.lax.map(
+            lambda w: ms._chunk_parents_pair(g, w, shifts), words)).lower(
+                g, _chunk_words(depth))
+    text = lowered.as_text()
+    operands = re.findall(
+        rf'"stablehlo\.gather".* : \((tensor<[^>]*>), tensor<{m}x1xi32>\)',
+        text)
+    assert sorted(operands) == sorted(
+        [f"tensor<{n}xui32>"] * gathers + [f"tensor<{n + 1}xi32>"])
