@@ -189,7 +189,7 @@ class LaneEngine:
         cap = min(self.lanes, DEFAULT_LANES) if self.lanes else DEFAULT_LANES
         return max(1, min(num_roots, cap))
 
-    def sssp_sweep(self, roots, delta=None):
+    def sssp_sweep(self, roots, delta=None, derive_parents: bool = False):
         """One pipelined delta-stepping sweep over the engine's weighted
         graph; returns ``repro.traversal.sssp.SSSPResult`` (``dist`` is
         [n, R] float32 with the original vertex count, inf unreached).
@@ -200,7 +200,9 @@ class LaneEngine:
         (``compress=True`` ships the per-step value exchanges through the
         sparse codec) — all bit-identical per ``tests/test_dist_sssp.py``.
         ``delta`` is a scalar width or a per-lane tuple (the engines'
-        static knob; None picks the graph default)."""
+        static knob; None picks the graph default).
+        ``derive_parents=True`` adds ``parent``, an SSSP tree per source
+        (Graph 500 kernel 3); the host engine alone derives it."""
         if self.wg is None:
             raise TypeError(
                 "weighted sweep on an unweighted engine — build the "
@@ -210,6 +212,11 @@ class LaneEngine:
         roots = np.asarray(roots, np.int32).reshape(-1)
         if roots.size < 1:
             raise ValueError("need at least one source")
+        if derive_parents and (self.dwg2 is not None
+                               or self.dwg is not None):
+            raise NotImplementedError(
+                "SSSP parents are derived by the host engine only: build "
+                "the LaneEngine without ndev, mesh or grid to get them")
         lanes = self.sssp_lanes_for(roots.size)
         if self.dwg2 is not None:
             from repro.core.dist_sssp import dist2d_sssp
@@ -229,7 +236,8 @@ class LaneEngine:
                               lanes=lanes,
                               max_pos=self.max_pos,
                               relax_impl=self.probe_impl,
-                              recorder=self._recorder("sssp"))
+                              recorder=self._recorder("sssp"),
+                              derive_parents=derive_parents)
 
 
 def as_engine(g_or_engine, **kwargs) -> LaneEngine:

@@ -105,6 +105,20 @@ def test_sssp_step_compiles_at_scale20(graph, one_chip, lanes):
                                     ts.MAX_SSSP_STEPS).compile())
 
 
+def test_sssp_drain_with_parent_steps_compiles_at_scale20(graph, one_chip):
+    state = _on(jax.eval_shape(lambda: ts.sssp_engine_init(
+        graph, 2, 2, track_steps=True)), one_chip)
+    _fits(ts._sssp_drain.lower(graph, state, 0.03, 8, "xla",
+                               ts.MAX_SSSP_STEPS).compile())
+
+
+def test_sssp_parents_compile_at_scale20(graph, one_chip):
+    dist = _shape((N, 2), jnp.float32, one_chip)
+    steps = _shape((N, 2), jnp.int32, one_chip)
+    roots = _shape((2,), jnp.int32, one_chip)
+    _fits(ts._sssp_parents.lower(graph, dist, steps, roots).compile())
+
+
 def test_dist2d_step_compiles_on_2x2_mesh(topo):
     mesh = Mesh(np.asarray(topo.devices).reshape(2, 2), ("row", "col"))
     grid = 4
