@@ -35,7 +35,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from repro.core.csr import CSRGraph, WeightedCSRGraph
+from repro.core.csr import CSRGraph, WeightedCSRGraph, with_slot_pos
 from repro.core.hybrid import ALPHA_DEFAULT, BETA_DEFAULT
 from repro.core.msbfs import MSBFSResult, msbfs_pipelined
 from repro.core.packed import MODES, adaptive_lane_pool
@@ -73,6 +73,9 @@ class LaneEngine:
         # a repro.obs.Telemetry bundle; None (the default) keeps every
         # sweep on the recorder-off fused-drain path
         self.telemetry = telemetry
+        # the slot-to-row-position table, built once per graph here so no
+        # engine call rebuilds it
+        g = with_slot_pos(g)
         self.wg = g if isinstance(g, WeightedCSRGraph) else None
         self.g = g.csr if self.wg is not None else g
         g = self.g

@@ -5,6 +5,12 @@ Layout (all static shapes, jit-friendly):
   col_idx : int32[m]     neighbour ids, sorted within each row
   src_idx : int32[m]     CSR row expansion (owner of edge slot e) — enables
                          the edge-parallel top-down / fallback formulations
+  slot_pos: int32[m]     place of slot e in its row (``e - row_ptr[src]``),
+                         -1 on slots at or past ``row_ptr[-1]`` (the pad of
+                         a distributed edge slab). A constant of the graph:
+                         every builder fills it once (``with_slot_pos`` for
+                         a graph built by hand), so no traversal step
+                         gathers ``row_ptr`` at m indices to rebuild it
 
 ``col_idx`` entries are always valid vertex ids (no padding inside rows);
 edge-parallel code masks by frontier/visited state instead.
@@ -29,6 +35,7 @@ class CSRGraph(NamedTuple):
     row_ptr: jnp.ndarray  # int32[n+1]
     col_idx: jnp.ndarray  # int32[m]
     src_idx: jnp.ndarray  # int32[m]
+    slot_pos: jnp.ndarray | None = None  # int32[m], -1 past row_ptr[-1]
 
     @property
     def n(self) -> int:
@@ -48,6 +55,7 @@ class WeightedCSRGraph(NamedTuple):
     col_idx: jnp.ndarray  # int32[m]
     src_idx: jnp.ndarray  # int32[m]
     weights: jnp.ndarray  # float32[m] — weight of edge src_idx[e]->col_idx[e]
+    slot_pos: jnp.ndarray | None = None  # int32[m], -1 past row_ptr[-1]
 
     @property
     def n(self) -> int:
@@ -67,7 +75,26 @@ class WeightedCSRGraph(NamedTuple):
         MS-BFS engines, the analytics sweeps) takes this; the weights ride
         alongside for the tropical/numeric semirings only."""
         return CSRGraph(row_ptr=self.row_ptr, col_idx=self.col_idx,
-                        src_idx=self.src_idx)
+                        src_idx=self.src_idx, slot_pos=self.slot_pos)
+
+
+@jax.jit
+def build_slot_pos(row_ptr: jnp.ndarray, src_idx: jnp.ndarray) -> jnp.ndarray:
+    """The ``slot_pos`` table from the slot-to-row table ``src_idx``: one
+    gather of ``row_ptr`` at m indices; -1 from ``row_ptr[-1]`` on."""
+    e = jnp.arange(src_idx.shape[0], dtype=jnp.int32)
+    return jnp.where(e < row_ptr[-1], e - row_ptr[src_idx], -1)
+
+
+def with_slot_pos(g):
+    """``g`` (a ``CSRGraph`` or ``WeightedCSRGraph``) with its
+    ``slot_pos`` table: ``g`` itself when it has one, else ``g`` with the
+    table built on the device by one gather of ``row_ptr``. Idempotent;
+    every jitted engine entry point calls it at its top, outside every
+    loop and conditional."""
+    if g.slot_pos is not None:
+        return g
+    return g._replace(slot_pos=build_slot_pos(g.row_ptr, g.src_idx))
 
 
 def _build_csr(src: np.ndarray, dst: np.ndarray, w, n: int,
@@ -129,11 +156,11 @@ def from_edges(src: np.ndarray, dst: np.ndarray, n: int,
     """
     row_ptr, dst, src, _ = _build_csr(src, dst, None, n, symmetrize,
                                       drop_self_loops, dedup)
-    return CSRGraph(
+    return with_slot_pos(CSRGraph(
         row_ptr=jnp.asarray(row_ptr, dtype=jnp.int32),
         col_idx=jnp.asarray(dst, dtype=jnp.int32),
         src_idx=jnp.asarray(src, dtype=jnp.int32),
-    )
+    ))
 
 
 def from_weighted_edges(src: np.ndarray, dst: np.ndarray, w: np.ndarray,
@@ -161,12 +188,12 @@ def from_weighted_edges(src: np.ndarray, dst: np.ndarray, w: np.ndarray,
             f"weights")
     row_ptr, dst, src, w = _build_csr(src, dst, w, n, symmetrize,
                                       drop_self_loops, dedup)
-    return WeightedCSRGraph(
+    return with_slot_pos(WeightedCSRGraph(
         row_ptr=jnp.asarray(row_ptr, dtype=jnp.int32),
         col_idx=jnp.asarray(dst, dtype=jnp.int32),
         src_idx=jnp.asarray(src, dtype=jnp.int32),
         weights=jnp.asarray(w, dtype=jnp.float32),
-    )
+    ))
 
 
 def to_numpy_adj(g: CSRGraph) -> tuple[np.ndarray, np.ndarray]:

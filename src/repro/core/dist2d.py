@@ -77,7 +77,7 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.core.csr import CSRGraph
+from repro.core.csr import CSRGraph, build_slot_pos
 from repro.core.dist_msbfs import first_devices
 from repro.core.exchange import exchange_expand, exchange_reduce_or
 from repro.core.hybrid import ALPHA_DEFAULT, BETA_DEFAULT, MAX_TRACE
@@ -106,6 +106,7 @@ class DistGraph2D:
     col_loc: jnp.ndarray   # int32[G, m_loc] — column-block-LOCAL dest ids
     col_gid: jnp.ndarray   # int32[G, m_loc] — global dest ids (parents)
     src_loc: jnp.ndarray   # int32[G, m_loc] — row-block-local source row
+    slot_pos: jnp.ndarray  # int32[G, m_loc] — place in row, -1 pads
     deg: jnp.ndarray       # int32[G, n_loc_r] — PARTIAL (block) degrees
     n: int                 # padded global vertex count (G * chunk)
     n_orig: int            # original vertex count
@@ -181,9 +182,11 @@ def partition_graph_2d(g: CSRGraph, pr: int, pc: int) -> DistGraph2D:
         col_loc[d, :k] = slabs_loc[d]
         col_gid[d, :k] = slabs_gid[d]
         src_l[d, :k] = srcs[d]
+    row_ptr_l, src_l = jnp.asarray(row_ptr_l), jnp.asarray(src_l)
     return DistGraph2D(
-        row_ptr=jnp.asarray(row_ptr_l), col_loc=jnp.asarray(col_loc),
-        col_gid=jnp.asarray(col_gid), src_loc=jnp.asarray(src_l),
+        row_ptr=row_ptr_l, col_loc=jnp.asarray(col_loc),
+        col_gid=jnp.asarray(col_gid), src_loc=src_l,
+        slot_pos=jax.vmap(build_slot_pos)(row_ptr_l, src_l),
         deg=jnp.asarray(deg_l), n=n, n_orig=n_orig, pr=pr, pc=pc,
         chunk=chunk, m_loc=m_loc)
 
@@ -427,7 +430,7 @@ def _dist2d_pipeline_body(g_loc: CSRGraph, base_r, chunk_base,
 @partial(jax.jit, static_argnames=("mesh", "mode", "alpha", "beta",
                                    "max_pos", "probe_impl", "n", "n_loc_r",
                                    "chunk", "n_orig", "compress", "drain"))
-def _dist2d_engine_run(row_ptr_s, colloc_s, srcloc_s,
+def _dist2d_engine_run(row_ptr_s, colloc_s, srcloc_s, pos_s,
                        state: Dist2DPipelineState, *, mesh: Mesh, mode: str,
                        alpha: float, beta: float, max_pos: int,
                        probe_impl: str, n: int, n_loc_r: int, chunk: int,
@@ -435,10 +438,10 @@ def _dist2d_engine_run(row_ptr_s, colloc_s, srcloc_s,
                        drain: bool) -> Dist2DPipelineState:
     cap = state.queue.shape[0]
 
-    def body(row_ptr, col_loc, src_loc, s: Dist2DPipelineState):
+    def body(row_ptr, col_loc, src_loc, pos, s: Dist2DPipelineState):
         # strip the stacked device dims from the sharded leaves
         g_loc = CSRGraph(row_ptr=row_ptr[0], col_idx=col_loc[0],
-                         src_idx=src_loc[0])
+                         src_idx=src_loc[0], slot_pos=pos[0])
         i = jax.lax.axis_index("row")
         j = jax.lax.axis_index("col")
         base_r = (i * n_loc_r).astype(jnp.int32)     # row block start
@@ -464,9 +467,9 @@ def _dist2d_engine_run(row_ptr_s, colloc_s, srcloc_s,
     specs = _state_specs_2d()
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(spec_dev, spec_dev, spec_dev, specs),
+        in_specs=(spec_dev, spec_dev, spec_dev, spec_dev, specs),
         out_specs=specs, check_vma=False,
-    )(row_ptr_s, colloc_s, srcloc_s, state)
+    )(row_ptr_s, colloc_s, srcloc_s, pos_s, state)
 
 
 def dist2d_msbfs_engine_step(dg: DistGraph2D, state: Dist2DPipelineState,
@@ -480,10 +483,10 @@ def dist2d_msbfs_engine_step(dg: DistGraph2D, state: Dist2DPipelineState,
         raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
     _check_partition_2d(dg, mesh)
     return _dist2d_engine_run(
-        dg.row_ptr, dg.col_loc, dg.src_loc, state, mesh=mesh, mode=mode,
-        alpha=alpha, beta=beta, max_pos=max_pos, probe_impl=probe_impl,
-        n=dg.n, n_loc_r=dg.n_loc_r, chunk=dg.chunk, n_orig=dg.n_orig,
-        compress=compress, drain=False)
+        dg.row_ptr, dg.col_loc, dg.src_loc, dg.slot_pos, state, mesh=mesh,
+        mode=mode, alpha=alpha, beta=beta, max_pos=max_pos,
+        probe_impl=probe_impl, n=dg.n, n_loc_r=dg.n_loc_r, chunk=dg.chunk,
+        n_orig=dg.n_orig, compress=compress, drain=False)
 
 
 def dist2d_msbfs_engine_drain(dg: DistGraph2D, state: Dist2DPipelineState,
@@ -497,10 +500,10 @@ def dist2d_msbfs_engine_drain(dg: DistGraph2D, state: Dist2DPipelineState,
         raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
     _check_partition_2d(dg, mesh)
     return _dist2d_engine_run(
-        dg.row_ptr, dg.col_loc, dg.src_loc, state, mesh=mesh, mode=mode,
-        alpha=alpha, beta=beta, max_pos=max_pos, probe_impl=probe_impl,
-        n=dg.n, n_loc_r=dg.n_loc_r, chunk=dg.chunk, n_orig=dg.n_orig,
-        compress=compress, drain=True)
+        dg.row_ptr, dg.col_loc, dg.src_loc, dg.slot_pos, state, mesh=mesh,
+        mode=mode, alpha=alpha, beta=beta, max_pos=max_pos,
+        probe_impl=probe_impl, n=dg.n, n_loc_r=dg.n_loc_r, chunk=dg.chunk,
+        n_orig=dg.n_orig, compress=compress, drain=True)
 
 
 @partial(jax.jit, static_argnames=("mesh", "n", "n_loc_r", "num_roots",

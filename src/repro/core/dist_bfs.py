@@ -32,7 +32,7 @@ from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
 from repro.core import bitmap
-from repro.core.csr import CSRGraph
+from repro.core.csr import CSRGraph, build_slot_pos
 
 MAX_LAYERS = 64
 
@@ -53,6 +53,7 @@ class DistGraph:
     row_ptr: jnp.ndarray   # int32[ndev, n_loc+1] — local offsets into col_idx
     col_idx: jnp.ndarray   # int32[ndev, m_loc]   — global neighbour ids
     src_loc: jnp.ndarray   # int32[ndev, m_loc]   — local row of each edge
+    slot_pos: jnp.ndarray  # int32[ndev, m_loc]   — place in row, -1 pads
     deg: jnp.ndarray       # int32[ndev, n_loc]
     n: int                 # padded global vertex count (multiple of ndev*32)
     n_orig: int            # original vertex count
@@ -91,9 +92,12 @@ def partition_graph(g: CSRGraph, ndev: int) -> DistGraph:
         col_l[d, :len(slabs[d])] = slabs[d]
         src_l[d, :len(srcs[d])] = srcs[d]
     # Padded edge slots: src_loc points at a vertex whose row is full, so
-    # pos_e >= deg never activates them; col sentinel n fails bitmap tests.
-    return DistGraph(row_ptr=jnp.asarray(row_ptr_l),
-                     col_idx=jnp.asarray(col_l), src_loc=jnp.asarray(src_l),
+    # pos_e >= deg never activates them; col sentinel n fails bitmap tests;
+    # their slot_pos is -1.
+    row_ptr_l, src_l = jnp.asarray(row_ptr_l), jnp.asarray(src_l)
+    return DistGraph(row_ptr=row_ptr_l, col_idx=jnp.asarray(col_l),
+                     src_loc=src_l,
+                     slot_pos=jax.vmap(build_slot_pos)(row_ptr_l, src_l),
                      deg=jnp.asarray(deg_l), n=n, n_orig=n_orig, m_loc=m_loc)
 
 

@@ -278,7 +278,7 @@ def _dist_pipeline_body(g_loc: CSRGraph, base, s: DistPipelineState,
 @partial(jax.jit, static_argnames=("mesh", "mode", "alpha", "beta",
                                    "max_pos", "probe_impl", "n", "n_loc",
                                    "n_orig", "drain"))
-def _dist_engine_run(row_ptr_s, col_s, srcloc_s, deg_s,
+def _dist_engine_run(row_ptr_s, col_s, srcloc_s, pos_s, deg_s,
                      state: DistPipelineState, *, mesh: Mesh, mode: str,
                      alpha: float, beta: float, max_pos: int,
                      probe_impl: str, n: int, n_loc: int, n_orig: int,
@@ -286,10 +286,10 @@ def _dist_engine_run(row_ptr_s, col_s, srcloc_s, deg_s,
     axes = tuple(mesh.axis_names)
     cap = state.queue.shape[0]
 
-    def body(row_ptr, col, src_loc, deg, s: DistPipelineState):
+    def body(row_ptr, col, src_loc, pos, deg, s: DistPipelineState):
         # strip the stacked device dim from the sharded leaves
         g_loc = CSRGraph(row_ptr=row_ptr[0], col_idx=col[0],
-                         src_idx=src_loc[0])
+                         src_idx=src_loc[0], slot_pos=pos[0])
         del deg   # g_loc.deg (row_ptr diffs) == the stored per-device deg
         base = _flat_axis_index(axes, dict(mesh.shape)) * n_loc
         s = s._replace(visited=s.visited[0], depth=s.depth[0],
@@ -313,9 +313,9 @@ def _dist_engine_run(row_ptr_s, col_s, srcloc_s, deg_s,
     specs = _state_specs(axes)
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(spec_dev, spec_dev, spec_dev, spec_dev, specs),
+        in_specs=(spec_dev, spec_dev, spec_dev, spec_dev, spec_dev, specs),
         out_specs=specs, check_vma=False,
-    )(row_ptr_s, col_s, srcloc_s, deg_s, state)
+    )(row_ptr_s, col_s, srcloc_s, pos_s, deg_s, state)
 
 
 def dist_msbfs_engine_step(dg: DistGraph, state: DistPipelineState,
@@ -332,8 +332,8 @@ def dist_msbfs_engine_step(dg: DistGraph, state: DistPipelineState,
         raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
     ndev = _check_partition(dg, mesh)
     return _dist_engine_run(
-        dg.row_ptr, dg.col_idx, dg.src_loc, dg.deg, state, mesh=mesh,
-        mode=mode, alpha=alpha, beta=beta, max_pos=max_pos,
+        dg.row_ptr, dg.col_idx, dg.src_loc, dg.slot_pos, dg.deg, state,
+        mesh=mesh, mode=mode, alpha=alpha, beta=beta, max_pos=max_pos,
         probe_impl=probe_impl, n=dg.n, n_loc=dg.n // ndev,
         n_orig=dg.n_orig, drain=False)
 
@@ -348,8 +348,8 @@ def dist_msbfs_engine_drain(dg: DistGraph, state: DistPipelineState,
         raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
     ndev = _check_partition(dg, mesh)
     return _dist_engine_run(
-        dg.row_ptr, dg.col_idx, dg.src_loc, dg.deg, state, mesh=mesh,
-        mode=mode, alpha=alpha, beta=beta, max_pos=max_pos,
+        dg.row_ptr, dg.col_idx, dg.src_loc, dg.slot_pos, dg.deg, state,
+        mesh=mesh, mode=mode, alpha=alpha, beta=beta, max_pos=max_pos,
         probe_impl=probe_impl, n=dg.n, n_loc=dg.n // ndev,
         n_orig=dg.n_orig, drain=True)
 

@@ -94,6 +94,7 @@ class DistWeightedGraph:
     row_ptr: jnp.ndarray   # int32[ndev, n_loc+1]
     col_idx: jnp.ndarray   # int32[ndev, m_loc] — global neighbour ids
     src_loc: jnp.ndarray   # int32[ndev, m_loc]
+    slot_pos: jnp.ndarray  # int32[ndev, m_loc] — place in row, -1 pads
     deg: jnp.ndarray       # int32[ndev, n_loc]
     weights: jnp.ndarray   # float32[ndev, m_loc] — inf pads
     n: int                 # padded global vertex count
@@ -117,8 +118,8 @@ def partition_weighted_graph(wg: WeightedCSRGraph,
             w_l[d, :len(slab)] = slab
     return DistWeightedGraph(
         row_ptr=dg.row_ptr, col_idx=dg.col_idx, src_loc=dg.src_loc,
-        deg=dg.deg, weights=jnp.asarray(w_l), n=dg.n, n_orig=dg.n_orig,
-        m_loc=dg.m_loc)
+        slot_pos=dg.slot_pos, deg=dg.deg, weights=jnp.asarray(w_l), n=dg.n,
+        n_orig=dg.n_orig, m_loc=dg.m_loc)
 
 
 @dataclass(frozen=True)
@@ -457,16 +458,16 @@ def _dist_sssp_body(gw_loc, base, s: DistSSSPState, delta, max_pos: int,
 @partial(jax.jit, static_argnames=("mesh", "delta", "max_pos", "relax_impl",
                                    "max_steps", "n", "n_loc", "compress",
                                    "drain"))
-def _dist_sssp_run(row_ptr_s, col_s, srcloc_s, w_s, state: DistSSSPState, *,
-                   mesh: Mesh, delta, max_pos: int, relax_impl: str,
-                   max_steps: int, n: int, n_loc: int, compress: bool,
-                   drain: bool) -> DistSSSPState:
+def _dist_sssp_run(row_ptr_s, col_s, srcloc_s, pos_s, w_s,
+                   state: DistSSSPState, *, mesh: Mesh, delta, max_pos: int,
+                   relax_impl: str, max_steps: int, n: int, n_loc: int,
+                   compress: bool, drain: bool) -> DistSSSPState:
     axes = tuple(mesh.axis_names)
     cap = state.queue.shape[0]
 
-    def body(row_ptr, col, src_loc, w, s: DistSSSPState):
+    def body(row_ptr, col, src_loc, pos, w, s: DistSSSPState):
         g_loc = CSRGraph(row_ptr=row_ptr[0], col_idx=col[0],
-                         src_idx=src_loc[0])
+                         src_idx=src_loc[0], slot_pos=pos[0])
         base = _flat_axis_index(axes, dict(mesh.shape)) * n_loc
         step = partial(_dist_sssp_body, (g_loc, w[0]), base, delta=delta,
                        max_pos=max_pos, relax_impl=relax_impl,
@@ -485,9 +486,9 @@ def _dist_sssp_run(row_ptr_s, col_s, srcloc_s, w_s, state: DistSSSPState, *,
     specs = _state_specs_1d()
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(spec_dev, spec_dev, spec_dev, spec_dev, specs),
+        in_specs=(spec_dev, spec_dev, spec_dev, spec_dev, spec_dev, specs),
         out_specs=specs, check_vma=False,
-    )(row_ptr_s, col_s, srcloc_s, w_s, state)
+    )(row_ptr_s, col_s, srcloc_s, pos_s, w_s, state)
 
 
 def dist_sssp_engine_step(dwg: DistWeightedGraph, state: DistSSSPState,
@@ -500,8 +501,8 @@ def dist_sssp_engine_step(dwg: DistWeightedGraph, state: DistSSSPState,
     _check_delta(delta)
     ndev = _check_partition_1d(dwg, mesh)
     return _dist_sssp_run(
-        dwg.row_ptr, dwg.col_idx, dwg.src_loc, dwg.weights, state,
-        mesh=mesh, delta=delta, max_pos=max_pos, relax_impl=relax_impl,
+        dwg.row_ptr, dwg.col_idx, dwg.src_loc, dwg.slot_pos, dwg.weights,
+        state, mesh=mesh, delta=delta, max_pos=max_pos, relax_impl=relax_impl,
         max_steps=max_steps, n=dwg.n, n_loc=dwg.n // ndev,
         compress=compress, drain=False)
 
@@ -515,8 +516,8 @@ def dist_sssp_engine_drain(dwg: DistWeightedGraph, state: DistSSSPState,
     _check_delta(delta)
     ndev = _check_partition_1d(dwg, mesh)
     return _dist_sssp_run(
-        dwg.row_ptr, dwg.col_idx, dwg.src_loc, dwg.weights, state,
-        mesh=mesh, delta=delta, max_pos=max_pos, relax_impl=relax_impl,
+        dwg.row_ptr, dwg.col_idx, dwg.src_loc, dwg.slot_pos, dwg.weights,
+        state, mesh=mesh, delta=delta, max_pos=max_pos, relax_impl=relax_impl,
         max_steps=max_steps, n=dwg.n, n_loc=dwg.n // ndev,
         compress=compress, drain=True)
 
@@ -741,16 +742,16 @@ def _dist2d_sssp_body(gw_loc, base_r, chunk_base, s: DistSSSPState, delta,
 @partial(jax.jit, static_argnames=("mesh", "delta", "max_pos", "relax_impl",
                                    "max_steps", "n", "n_loc_r", "chunk",
                                    "compress", "drain"))
-def _dist2d_sssp_run(row_ptr_s, colloc_s, srcloc_s, w_s,
+def _dist2d_sssp_run(row_ptr_s, colloc_s, srcloc_s, pos_s, w_s,
                      state: DistSSSPState, *, mesh: Mesh, delta,
                      max_pos: int, relax_impl: str, max_steps: int, n: int,
                      n_loc_r: int, chunk: int, compress: bool,
                      drain: bool) -> DistSSSPState:
     cap = state.queue.shape[0]
 
-    def body(row_ptr, col_loc, src_loc, w, s: DistSSSPState):
+    def body(row_ptr, col_loc, src_loc, pos, w, s: DistSSSPState):
         g_loc = CSRGraph(row_ptr=row_ptr[0], col_idx=col_loc[0],
-                         src_idx=src_loc[0])
+                         src_idx=src_loc[0], slot_pos=pos[0])
         i = jax.lax.axis_index("row")
         j = jax.lax.axis_index("col")
         base_r = (i * n_loc_r).astype(jnp.int32)     # row block start
@@ -776,9 +777,9 @@ def _dist2d_sssp_run(row_ptr_s, colloc_s, srcloc_s, w_s,
     specs = _state_specs_2d()
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(spec_dev, spec_dev, spec_dev, spec_dev, specs),
+        in_specs=(spec_dev, spec_dev, spec_dev, spec_dev, spec_dev, specs),
         out_specs=specs, check_vma=False,
-    )(row_ptr_s, colloc_s, srcloc_s, w_s, state)
+    )(row_ptr_s, colloc_s, srcloc_s, pos_s, w_s, state)
 
 
 def dist2d_sssp_engine_step(dwg2: DistWeightedGraph2D, state: DistSSSPState,
@@ -791,8 +792,8 @@ def dist2d_sssp_engine_step(dwg2: DistWeightedGraph2D, state: DistSSSPState,
     g2 = dwg2.g2
     _check_partition_2d(g2, mesh)
     return _dist2d_sssp_run(
-        g2.row_ptr, g2.col_loc, g2.src_loc, dwg2.weights, state, mesh=mesh,
-        delta=delta, max_pos=max_pos, relax_impl=relax_impl,
+        g2.row_ptr, g2.col_loc, g2.src_loc, g2.slot_pos, dwg2.weights,
+        state, mesh=mesh, delta=delta, max_pos=max_pos, relax_impl=relax_impl,
         max_steps=max_steps, n=g2.n, n_loc_r=g2.n_loc_r, chunk=g2.chunk,
         compress=compress, drain=False)
 
@@ -807,8 +808,8 @@ def dist2d_sssp_engine_drain(dwg2: DistWeightedGraph2D, state: DistSSSPState,
     g2 = dwg2.g2
     _check_partition_2d(g2, mesh)
     return _dist2d_sssp_run(
-        g2.row_ptr, g2.col_loc, g2.src_loc, dwg2.weights, state, mesh=mesh,
-        delta=delta, max_pos=max_pos, relax_impl=relax_impl,
+        g2.row_ptr, g2.col_loc, g2.src_loc, g2.slot_pos, dwg2.weights,
+        state, mesh=mesh, delta=delta, max_pos=max_pos, relax_impl=relax_impl,
         max_steps=max_steps, n=g2.n, n_loc_r=g2.n_loc_r, chunk=g2.chunk,
         compress=compress, drain=True)
 

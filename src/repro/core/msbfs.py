@@ -65,7 +65,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.csr import CSRGraph
+from repro.core.csr import CSRGraph, with_slot_pos
 from repro.core.hybrid import ALPHA_DEFAULT, BETA_DEFAULT, MAX_TRACE
 from repro.core.packed import (LANE_WORD_BITS, MODES, _dispatch_packed_step,
                                adaptive_lane_pool, depth_slice_words,
@@ -146,7 +146,7 @@ def _chunk_parents_keyed(g: CSRGraph, w: jnp.ndarray,
     at_col = (w[g.col_idx][None, :] >> shifts[:, None]) & 0xFF  # [k, m]
     key = jnp.where(at_col != 0, (at_col << _KEY_ID_BITS) | col[None, :],
                     jnp.uint32(_NO_KEY))
-    best = segment_scan_rows(key, g.row_ptr, g.src_idx, jnp.minimum,
+    best = segment_scan_rows(key, g.row_ptr, g.slot_pos, jnp.minimum,
                              _NO_KEY)                              # [k, n]
     own = (w[None, :] >> shifts[:, None]) & 0xFF   # the row's byte, no gather
     # the empty key's top byte (255) is above any byte + 1, so never matches
@@ -164,7 +164,7 @@ def _chunk_parents_pair(g: CSRGraph, w: jnp.ndarray,
     at_src = (w[src][None, :] >> shifts[:, None]) & 0xFF
     ok = (at_col != 0) & (at_col + 1 == at_src)
     cand = jnp.where(ok, col[None, :], n).astype(jnp.int32)
-    best = segment_scan_rows(cand, g.row_ptr, src, jnp.minimum, n)
+    best = segment_scan_rows(cand, g.row_ptr, g.slot_pos, jnp.minimum, n)
     return jnp.where(best < n, best, -1)                    # [k, n]
 
 
@@ -185,6 +185,7 @@ def _derive_parents(g: CSRGraph, depth: jnp.ndarray,
     (``_chunk_parents_pair``). The min-id rule matches the serial steps'
     deterministic scatter-min parent choice.
     """
+    g = with_slot_pos(g)
     n = g.n
     num_roots = roots.shape[0]
     if num_roots == 0:
@@ -217,6 +218,7 @@ def msbfs(g: CSRGraph, roots: jnp.ndarray, mode: str = "hybrid",
     """
     if mode not in MODES:
         raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
+    g = with_slot_pos(g)
     n = g.n
     roots = roots.astype(jnp.int32)
     num_roots = roots.shape[0]
@@ -522,12 +524,14 @@ def msbfs_engine_step(g: CSRGraph, state: PipelineState, mode: str = "hybrid",
     """
     if mode not in MODES:
         raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
-    return _pipeline_body(g, state, mode, alpha, beta, max_pos, probe_impl)
+    return _pipeline_body(with_slot_pos(g), state, mode, alpha, beta,
+                          max_pos, probe_impl)
 
 
 @partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
 def _drain(g: CSRGraph, state: PipelineState, mode: str, alpha: float,
            beta: float, max_pos: int, probe_impl: str) -> PipelineState:
+    g = with_slot_pos(g)
     cap = state.queue.shape[0]
 
     def cond_fn(s: PipelineState):

@@ -11,7 +11,8 @@ ONE implementation of the lane-word machinery serves both MS-BFS engines:
 The key property that makes sharing possible: every step function takes
 the graph as a ``CSRGraph`` *view* and only assumes
 
-  - ``row_ptr``/``src_idx`` index LOCAL rows (the rows this caller owns),
+  - ``row_ptr``/``src_idx``/``slot_pos`` index LOCAL rows (the rows this
+    caller owns),
   - ``col_idx`` holds GLOBAL neighbour ids (indices into ``frontier``),
   - ``frontier`` covers the full global vertex range,
   - ``visited``/``need`` cover the local rows only.
@@ -21,9 +22,14 @@ PR-1/PR-2 formulations; under ``shard_map`` each device passes its CSR
 block and the replicated full-width frontier, and the SAME code computes
 that device's slice of the next frontier. Rows padded with the sentinel
 column id ``frontier.shape[0]`` (the distributed edge-slab pad) are
-neutralised by the ``pos < deg`` probe guard, the ``pos_e < deg`` fallback
-guard, and the segmented scan's read-out points all sitting before the pad
-region.
+neutralised by the ``pos < deg`` probe guard and by their ``slot_pos`` of
+-1, which keeps them out of the fallback and out of every fold of the
+segmented scan.
+
+A slot's place in its row is read from the graph's ``slot_pos`` table,
+never rebuilt inside a step: a gather of ``row_ptr`` at m indices in a
+``lax.cond`` branch of the drain loop is not hoisted by the compiler, so
+it would be paid once per step.
 
 Each piece of one engine step runs under a ``jax.named_scope`` from
 ``STEP_SCOPES``, so the device trace can split a step (the op-name path
@@ -43,7 +49,7 @@ import os
 import jax
 import jax.numpy as jnp
 
-from repro.core.csr import CSRGraph
+from repro.core.csr import CSRGraph, build_slot_pos
 from repro.core.hybrid import switch_direction
 
 # the single knob of the ROADMAP uint64-lane rung: settable per process
@@ -126,10 +132,11 @@ def depth_slice_words(depth: jnp.ndarray, max_depth,
 
 
 def segment_scan_rows(vals: jnp.ndarray, row_ptr: jnp.ndarray,
-                      src_idx: jnp.ndarray, add, zero) -> jnp.ndarray:
+                      slot_pos: jnp.ndarray, add, zero) -> jnp.ndarray:
     """Per-CSR-row ``add``-reduction of LANE-MAJOR edge values
-    ``[L, m] -> [L, n]``; empty rows produce ``zero``. ``src_idx[e]`` is
-    the row that owns edge slot ``e``.
+    ``[L, m] -> [L, n]``; empty rows produce ``zero``. ``slot_pos[e]`` is
+    edge slot ``e``'s place in its row (the graph's table, -1 past
+    ``row_ptr[-1]``).
 
     CSR rows are contiguous runs of edge slots, so this is a segmented
     inclusive scan read out at each row's last slot. It runs as a
@@ -137,24 +144,23 @@ def segment_scan_rows(vals: jnp.ndarray, row_ptr: jnp.ndarray,
     ``i`` while both sit in one row, so ``ceil(log2(longest row))`` steps
     of whole-array ``add`` finish it. Every step has the same shape and the
     edge axis stays the minor (densely tiled) one on a TPU. A slot's place
-    in its row comes from ``src_idx``, not from a cumulative max over the
-    row starts: on a TPU that scan over millions of slots takes most of a
-    minute to compile. ``add`` must be associative and commutative; for OR
-    and min the result is exact. Slots past ``row_ptr[-1]`` (distributed
-    edge-slab padding) only feed later slots, all past every read-out
-    point.
+    in its row comes from the stored table, not from a cumulative max over
+    the row starts: on a TPU that scan over millions of slots takes most of
+    a minute to compile. ``add`` must be associative and commutative; for
+    OR and min the result is exact. Slots past ``row_ptr[-1]`` (distributed
+    edge-slab padding) hold -1, so they never fold, and they lie past every
+    read-out point.
     """
     m = vals.shape[-1]
     n = row_ptr.shape[0] - 1
     if m == 0:
         return jnp.full(vals.shape[:-1] + (n,), zero, vals.dtype)
-    back = jnp.arange(m, dtype=jnp.int32) - row_ptr[src_idx]  # place in row
     deg = row_ptr[1:] - row_ptr[:-1]
     longest = jnp.max(deg)
 
     def double(c):
         step, x = c
-        return 2 * step, jnp.where(back >= step,
+        return 2 * step, jnp.where(slot_pos >= step,
                                    add(x, jnp.roll(x, step, axis=-1)), x)
 
     _, scanned = jax.lax.while_loop(lambda c: c[0] < longest, double,
@@ -164,14 +170,6 @@ def segment_scan_rows(vals: jnp.ndarray, row_ptr: jnp.ndarray,
                      jnp.asarray(zero, vals.dtype))
 
 
-def slot_rows(row_ptr: jnp.ndarray, m: int) -> jnp.ndarray:
-    """int32[m] row of each edge slot, from ``row_ptr`` alone (slots past
-    ``row_ptr[-1]`` land on the last row)."""
-    n = row_ptr.shape[0] - 1
-    return jnp.repeat(jnp.arange(n, dtype=jnp.int32),
-                      row_ptr[1:] - row_ptr[:-1], total_repeat_length=m)
-
-
 def gather_lanes(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     """``table[idx].T`` built lane by lane: ``[n, L]`` rows gathered at
     ``idx[m]`` as a lane-major ``[L, m]`` array. One 1-D gather per lane
@@ -179,11 +177,20 @@ def gather_lanes(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
     return jnp.stack([table[:, lane][idx] for lane in range(table.shape[1])])
 
 
+def row_slot_pos(row_ptr: jnp.ndarray, m: int) -> jnp.ndarray:
+    """The ``slot_pos`` table of ``m`` slots over the rows of ``row_ptr``
+    alone, for the reductions that take no graph."""
+    n = row_ptr.shape[0] - 1
+    rows = jnp.repeat(jnp.arange(n, dtype=jnp.int32),
+                      row_ptr[1:] - row_ptr[:-1], total_repeat_length=m)
+    return build_slot_pos(row_ptr, rows)
+
+
 def segment_or(vals: jnp.ndarray, row_ptr: jnp.ndarray) -> jnp.ndarray:
     """Per-CSR-row bitwise OR of uint32[m, W] edge-lane words -> uint32[n, W]
     (``segment_scan_rows`` in the engines' row-major layout)."""
     return segment_scan_rows(vals.T, row_ptr,
-                             slot_rows(row_ptr, vals.shape[0]),
+                             row_slot_pos(row_ptr, vals.shape[0]),
                              jnp.bitwise_or, 0).T
 
 
@@ -229,14 +236,11 @@ def bottomup_packed_step(g: CSRGraph, frontier: jnp.ndarray,
         residue = ((need & ~found) != 0).any(axis=-1) & (g.deg > max_pos)
 
     def run_fallback(found):
-        pos_e = jnp.arange(g.m, dtype=jnp.int32) - g.row_ptr[g.src_idx]
-        # pos_e < deg keeps pad slots (distributed slab tail) inert: their
-        # src row is already full, so they never contribute
-        act = (residue[g.src_idx] & (pos_e >= max_pos)
-               & (pos_e < g.deg[g.src_idx]))
+        # pad slots (distributed slab tail) hold slot_pos -1: never active
+        act = residue[g.src_idx] & (g.slot_pos >= max_pos)
         contrib = jnp.where(act, gather_lanes(frontier, g.col_idx),
                             jnp.zeros((), frontier.dtype))       # [W, m]
-        return found | (segment_scan_rows(contrib, g.row_ptr, g.src_idx,
+        return found | (segment_scan_rows(contrib, g.row_ptr, g.slot_pos,
                                           jnp.bitwise_or, 0).T & need)
 
     with jax.named_scope("bfs_bu_fallback"):
@@ -254,7 +258,7 @@ def topdown_packed_step(g: CSRGraph, frontier: jnp.ndarray,
     with jax.named_scope("bfs_topdown"):
         col = jnp.clip(g.col_idx, 0, frontier.shape[0] - 1)
         contrib = gather_lanes(frontier, col) & td_sel[:, None]   # [W, m]
-        return segment_scan_rows(contrib, g.row_ptr, g.src_idx,
+        return segment_scan_rows(contrib, g.row_ptr, g.slot_pos,
                                  jnp.bitwise_or, 0).T & ~visited
 
 

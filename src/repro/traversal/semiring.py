@@ -41,8 +41,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.csr import CSRGraph
-from repro.core.packed import (gather_lanes, segment_scan_rows,
-                               slot_rows)
+from repro.core.packed import (gather_lanes, row_slot_pos,
+                               segment_scan_rows)
 
 __all__ = ["BOOLEAN", "PLUS_TIMES", "SEMIRINGS", "Semiring", "TROPICAL",
            "segment_reduce", "semiring_spmv", "tropical_relax"]
@@ -82,10 +82,10 @@ def segment_reduce(vals: jnp.ndarray, row_ptr: jnp.ndarray,
                    sr: Semiring) -> jnp.ndarray:
     """Per-CSR-row semiring ADD of edge-lane values [m, L] -> [n, L] —
     ``packed.segment_scan_rows`` in row-major layout. Empty rows produce
-    ``sr.zero``; slots past ``row_ptr[-1]`` only extend the last segment
-    beyond every read-out point."""
+    ``sr.zero``; slots past ``row_ptr[-1]`` never fold and lie beyond
+    every read-out point."""
     return segment_scan_rows(vals.T, row_ptr,
-                             slot_rows(row_ptr, vals.shape[0]), sr.add,
+                             row_slot_pos(row_ptr, vals.shape[0]), sr.add,
                              sr.zero).T
 
 
@@ -106,7 +106,7 @@ def semiring_spmv(g: CSRGraph, vals: jnp.ndarray, weights, sr: Semiring,
     contrib = gather_lanes(vals, col)                             # [L, m]
     if weights is not None:
         contrib = sr.mul(contrib, weights.astype(vals.dtype))
-    return segment_scan_rows(contrib, g.row_ptr, g.src_idx, sr.add,
+    return segment_scan_rows(contrib, g.row_ptr, g.slot_pos, sr.add,
                              sr.zero).T
 
 
@@ -114,14 +114,13 @@ def _relax_fallback(g: CSRGraph, weights: jnp.ndarray, vals: jnp.ndarray,
                     max_pos: int) -> jnp.ndarray:
     """Segmented-min over edge slots at position >= ``max_pos`` of rows
     deeper than ``max_pos`` — the residue the gather-relax probe skipped.
-    Inert slots contribute inf; pad slots (distributed edge slabs) sit
-    past every read-out point, same argument as ``segment_or``."""
-    pos_e = jnp.arange(g.m, dtype=jnp.int32) - g.row_ptr[g.src_idx]
-    act = (pos_e >= max_pos) & (pos_e < g.deg[g.src_idx])
+    Inert slots contribute inf; pad slots (distributed edge slabs) hold
+    ``slot_pos`` -1, so they are inert too."""
+    act = g.slot_pos >= max_pos
     cand = gather_lanes(vals, jnp.clip(g.col_idx, 0, vals.shape[0] - 1)) \
         + weights.astype(vals.dtype)                              # [L, m]
     cand = jnp.where(act, cand, INF)
-    return segment_scan_rows(cand, g.row_ptr, g.src_idx, TROPICAL.add,
+    return segment_scan_rows(cand, g.row_ptr, g.slot_pos, TROPICAL.add,
                              INF).T
 
 

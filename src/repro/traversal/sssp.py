@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.csr import WeightedCSRGraph
+from repro.core.csr import WeightedCSRGraph, with_slot_pos
 from repro.core.packed import queue_claims
 from repro.traversal.semiring import INF, tropical_relax
 
@@ -467,12 +467,14 @@ def sssp_engine_step(wg: WeightedCSRGraph, state: SSSPState, delta,
     feed idle lanes mid-sweep, exactly like the MS-BFS engine it mirrors.
     """
     _check_delta(delta)
-    return _sssp_body(wg, state, delta, max_pos, relax_impl, max_steps)
+    return _sssp_body(with_slot_pos(wg), state, delta, max_pos, relax_impl,
+                      max_steps)
 
 
 @partial(jax.jit, static_argnums=(2, 3, 4, 5))
 def _sssp_drain(wg: WeightedCSRGraph, state: SSSPState, delta,
                 max_pos: int, relax_impl: str, max_steps: int) -> SSSPState:
+    wg = with_slot_pos(wg)
     cap = state.queue.shape[0]
 
     def cond_fn(s: SSSPState):
@@ -524,8 +526,9 @@ def _less(a: tuple, b: tuple) -> jnp.ndarray:
 def _row_lexmin(keys: tuple, back: jnp.ndarray, row_ptr: jnp.ndarray):
     """Per CSR row, the lexicographic minimum of the slot tuples ``keys``
     (each ``[m]``): ``packed.segment_scan_rows``' doubling scan with a
-    tuple-valued min. ``back[e]`` is slot e's place in its row. Rows with
-    no slots read a neighbour's value; callers mask them by degree."""
+    tuple-valued min. ``back[e]`` is slot e's place in its row (the
+    graph's ``slot_pos``). Rows with no slots read a neighbour's value;
+    callers mask them by degree."""
     m = back.shape[0]
     longest = jnp.max(row_ptr[1:] - row_ptr[:-1])
 
@@ -569,10 +572,10 @@ def _sssp_parents(wg: WeightedCSRGraph, dist: jnp.ndarray,
     where some row's best candidate ties with it does a second pass
     gather ``t[col]`` as well (cond-skipped, lane by lane).
     """
+    wg = with_slot_pos(wg)
     n = wg.n
     with jax.named_scope("sssp_parents"):
-        col, w = wg.col_idx, wg.weights
-        back = jnp.arange(wg.m, dtype=jnp.int32) - wg.row_ptr[wg.src_idx]
+        col, w, back = wg.col_idx, wg.weights, wg.slot_pos
         has_slots = wg.deg > 0
         ids = jnp.arange(n, dtype=jnp.int32)
 

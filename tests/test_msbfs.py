@@ -590,9 +590,9 @@ def test_keyed_rule_equals_two_gather_rule(case):
 @pytest.mark.parametrize("rule,gathers", [("keyed", 1), ("pair", 2)])
 def test_parent_map_gathers_words_once_per_chunk(rule, gathers):
     """In the lowered program the map's body gathers the chunk's uint32[n]
-    words at m indices once (the two-gather rule: twice). The only other
-    gather over m indices is ``segment_scan_rows``' ``row_ptr[src_idx]``,
-    which does not depend on the chunk."""
+    words at m indices once (the two-gather rule: twice). No other gather
+    runs over m indices: a slot's place in its row is the graph's stored
+    ``slot_pos``, not ``row_ptr[src_idx]``."""
     import re
     g = rmat_graph(8, 8, seed=0)
     n, m = g.n, g.m
@@ -610,5 +610,4 @@ def test_parent_map_gathers_words_once_per_chunk(rule, gathers):
     operands = re.findall(
         rf'"stablehlo\.gather".* : \((tensor<[^>]*>), tensor<{m}x1xi32>\)',
         text)
-    assert sorted(operands) == sorted(
-        [f"tensor<{n}xui32>"] * gathers + [f"tensor<{n + 1}xi32>"])
+    assert sorted(operands) == [f"tensor<{n}xui32>"] * gathers

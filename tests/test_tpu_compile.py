@@ -131,6 +131,7 @@ def test_dist2d_step_compiles_on_2x2_mesh(topo):
                         col_loc=i32((grid, m_loc)),
                         col_gid=i32((grid, m_loc)),
                         src_loc=i32((grid, m_loc)),
+                        slot_pos=i32((grid, m_loc)),
                         deg=i32((grid, n_loc_r)), n=N, n_orig=N, pr=2,
                         pc=2, chunk=chunk, m_loc=m_loc)
     state = jax.eval_shape(
@@ -140,8 +141,9 @@ def test_dist2d_step_compiles_on_2x2_mesh(topo):
                                NamedSharding(mesh, spec)),
         state, d2._state_specs_2d())
     compiled = d2._dist2d_engine_run.lower(
-        dg.row_ptr, dg.col_loc, dg.src_loc, state, mesh=mesh, mode="hybrid",
-        alpha=14.0, beta=24.0, max_pos=8, probe_impl="xla", n=dg.n,
+        dg.row_ptr, dg.col_loc, dg.src_loc, dg.slot_pos, state, mesh=mesh,
+        mode="hybrid", alpha=14.0, beta=24.0, max_pos=8, probe_impl="xla",
+        n=dg.n,
         n_loc_r=dg.n_loc_r, chunk=dg.chunk, n_orig=dg.n_orig,
         compress=False, drain=False).compile()
     _fits(compiled)
